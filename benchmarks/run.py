@@ -57,7 +57,23 @@ def main(argv=None) -> int:
             os.environ.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count="
               f"{args.mesh_devices}").strip()
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
+        import jax
+
+        platform = jax.devices()[0].platform
+        if platform != "cpu":
+            print(f"error: --tables mesh is a CPU emulation of "
+                  f"{args.mesh_devices} host devices and refuses to run "
+                  f"where JAX sees a {platform!r} device; chip_smoke.py "
+                  f"--chips 4 drives the sharded path on chips",
+                  file=sys.stderr)
+            return 2
+        print(f"# mesh table: CPU emulation of {args.mesh_devices} host "
+              f"devices - partitioning overhead, not chip speed",
+              file=sys.stderr)
+
+    from repro.engine.persistent_cache import enable_persistent_cache
+
+    enable_persistent_cache()
 
     from benchmarks import kernel_bench, paper_tables
 

@@ -22,6 +22,7 @@ import numpy as np
 from repro.core import generators as G
 from repro.configs.service import ServiceConfig
 from repro.engine import AsyncChordalityEngine, backend_names, gather
+from repro.engine.persistent_cache import enable_persistent_cache
 
 REQUEST_KINDS = ("random_chordal", "sparse_random", "cycle", "random_tree")
 
@@ -57,6 +58,7 @@ def main():
                     help="registered backend, or 'auto' for cost-model "
                          "routing per drained work unit")
     args = ap.parse_args()
+    enable_persistent_cache()
 
     rng = np.random.default_rng(0)
     pairs = [synth_request(i, args.n_max, rng)

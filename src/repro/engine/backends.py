@@ -35,7 +35,7 @@ numpy_ref  no       no      yes          no     yes     yes   lexbfs_numpy_dense
 jax_faithful yes    yes     yes          no     yes     no    lexbfs (§6.1)
 jax_fast   yes      yes     yes          no     yes     yes   lexbfs_fast (lazy)
 pallas_peo no       yes     yes          no     yes     no    lexbfs + Pallas PEO
-sharded    yes      yes     no           no     no      no    shard_map over a mesh
+sharded    yes      yes     no           no     no      no    jit over a batch-sharded mesh
 csr        yes      yes     yes          yes    yes     no    repro.sparse CSR
 ========== ======== ======= ============ ====== ======= ===== ====================
 
@@ -356,9 +356,9 @@ class PallasPeoBackend(ChordalityBackend):
     ``pipeline="auto"`` (default) selects ``fused`` off-interpret (a real
     accelerator) and ``split`` under interpret mode, where the fused
     kernel's sequential emulation is the slower of the two on CPU.
-    ``interpret=None`` (default) resolves to ``jax.default_backend() !=
-    "tpu"`` — the same build is correct on CPU CI and compiles via Mosaic
-    on TPU. ``caps.batched`` stays False: it describes the *split* batch
+    ``interpret=None`` (default) resolves through
+    :func:`repro.kernels.resolve_interpret` (interpreted only off-TPU) —
+    the same build is correct on CPU CI and compiles via Mosaic on TPU. ``caps.batched`` stays False: it describes the *split* batch
     contract; fused units are natively batched and keyed separately.
 
     PR 6 adds two more compile-cache kinds (DESIGN.md §12):
@@ -384,12 +384,15 @@ class PallasPeoBackend(ChordalityBackend):
                  pipeline: str = "auto"):
         if pipeline not in ("auto", "fused", "split"):
             raise ValueError(f"unknown pallas_peo pipeline {pipeline!r}")
-        if interpret is None:
-            import jax
+        from repro.kernels import resolve_interpret
 
-            interpret = jax.default_backend() != "tpu"
-        self._interpret = bool(interpret)
+        self._interpret = resolve_interpret(interpret)
         self._pipeline = pipeline
+
+    @property
+    def interpret(self) -> bool:
+        """Whether this backend's kernels run in Pallas interpret mode."""
+        return self._interpret
 
     def verdict_kind(self, n_pad: int) -> str:
         from repro.configs.shapes import FUSED_MAX_NPAD, FUSED_PACK_MAX_NPAD
@@ -499,7 +502,7 @@ class PallasPeoBackend(ChordalityBackend):
 
 
 class ShardedBackend(ChordalityBackend):
-    """shard_map'd batch tester over an explicit 1-D device mesh — the
+    """Batch tester jit'd over an explicit 1-D device mesh — the
     multi-device production path (``repro.engine.mesh``, DESIGN.md §16).
 
     A work unit's batch axis is split across the mesh; each shard owns

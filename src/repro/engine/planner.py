@@ -213,6 +213,10 @@ class CompileCache:
     def __len__(self) -> int:
         return len(self._fns)
 
+    def keys(self):
+        """Cached ``(backend, scope, kind, n_pad, batch)`` keys."""
+        return list(self._fns)
+
     def get(self, backend, n_pad: int, batch: int,
             kind: str = "verdict") -> Callable:
         scope = backend.cache_scope()

@@ -24,6 +24,8 @@ pre-existing dispatch accounting is unchanged.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 from repro.obs.metrics import Counter
 from repro.obs.metrics import registry as _registry
 
@@ -62,4 +64,16 @@ class DispatchCounter:
 #: Process-global counter the kernel wrappers and backends tick.
 dispatch_counter = DispatchCounter()
 
-__all__ = ["DispatchCounter", "dispatch_counter"]
+
+def resolve_interpret(interpret: Optional[bool] = None) -> bool:
+    """Pallas interpret mode for a kernel call: an explicit value wins;
+    ``None`` interprets only when the default backend is not a TPU, so a
+    kernel never runs interpreted on a TPU unless the caller asks."""
+    if interpret is not None:
+        return bool(interpret)
+    import jax
+
+    return jax.default_backend() != "tpu"
+
+
+__all__ = ["DispatchCounter", "dispatch_counter", "resolve_interpret"]

@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_KV = 512
 NEG_INF = -1e30
@@ -121,7 +123,7 @@ def flash_attention_single_head(
     window: int | None = None,
     block_q: int = DEFAULT_BLOCK_Q,
     block_kv: int = DEFAULT_BLOCK_KV,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """q: (Sq, D), k/v: (Skv, D) -> (Sq, D). Assumes Sq == Skv offsets
     aligned (self-attention; decode uses the XLA path, not this kernel)."""
@@ -151,5 +153,5 @@ def flash_attention_single_head(
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

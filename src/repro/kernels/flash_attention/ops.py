@@ -32,7 +32,7 @@ def flash_attention(
     window: int | None = None,
     block_q: int = 512,
     block_kv: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     b, hq, sq, d = q.shape
     hkv = k.shape[1]

@@ -7,20 +7,21 @@ dispatch** — the whole per-bucket hot path behind a single ``pallas_call``
 the repo; verdicts to every PEO test (asserted in
 tests/test_lexbfs_fused.py).
 
-``interpret`` defaults to True (CPU-validated); on a real TPU deployment
-the wrapper is called with ``interpret=False`` and the same BlockSpecs
-compile via Mosaic. The module-level :data:`dispatch_counter` ticks once
+``interpret=None`` (default) resolves through
+:func:`repro.kernels.resolve_interpret`: interpreted on CPU hosts,
+compiled by Mosaic on a TPU. The module-level :data:`dispatch_counter` ticks once
 per host-level launch — benchmarks read it to report measured
 dispatches-per-unit (``BENCH_kernels.json``).
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import dispatch_counter
+from repro.kernels import dispatch_counter, resolve_interpret
 from repro.kernels.lexbfs_fused.lexbfs_fused import (
     compaction_block,
     lexbfs_peo_fused_call,
@@ -30,7 +31,7 @@ from repro.kernels.lexbfs_fused.lexbfs_fused import (
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _fused(adjs: jnp.ndarray, *, interpret: bool = True):
+def _fused(adjs: jnp.ndarray, *, interpret: bool):
     from repro.core.lexbfs import lexbfs_inner_block
 
     n = adjs.shape[1]
@@ -43,18 +44,19 @@ def _fused(adjs: jnp.ndarray, *, interpret: bool = True):
     return viols[:, 0] == 0, orders, viols[:, 0]
 
 
-def lexbfs_peo_fused(adjs: jnp.ndarray, *, interpret: bool = True):
+def lexbfs_peo_fused(adjs: jnp.ndarray, *,
+                     interpret: Optional[bool] = None):
     """(B, N, N) bool -> (verdicts (B,), orders (B, N), violations (B,)).
 
     One ``pallas_call`` per call — the one-dispatch-per-bucket contract
     the ``pallas_peo`` backend's ``pipeline="fused"`` serves.
     """
     dispatch_counter.tick()
-    return _fused(adjs, interpret=interpret)
+    return _fused(adjs, interpret=resolve_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _fused_witness(adjs: jnp.ndarray, *, interpret: bool = True):
+def _fused_witness(adjs: jnp.ndarray, *, interpret: bool):
     from repro.core.lexbfs import lexbfs_inner_block
 
     n = adjs.shape[1]
@@ -67,7 +69,8 @@ def _fused_witness(adjs: jnp.ndarray, *, interpret: bool = True):
     return viols[:, 0] == 0, orders, viols[:, 0], ln, parent, triple
 
 
-def lexbfs_peo_fused_witness(adjs: jnp.ndarray, *, interpret: bool = True):
+def lexbfs_peo_fused_witness(adjs: jnp.ndarray, *,
+                             interpret: Optional[bool] = None):
     """(B, N, N) bool -> (verdicts, orders, violations, ln, parent, triple).
 
     The certified hot path: one ``pallas_call`` emits the verdict *and*
@@ -77,11 +80,11 @@ def lexbfs_peo_fused_witness(adjs: jnp.ndarray, *, interpret: bool = True):
     ``repro.witness.witness_batch_from_fused_raw``.
     """
     dispatch_counter.tick()
-    return _fused_witness(adjs, interpret=interpret)
+    return _fused_witness(adjs, interpret=resolve_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("pack", "interpret"))
-def _fused_packed(adjs: jnp.ndarray, *, pack: int, interpret: bool = True):
+def _fused_packed(adjs: jnp.ndarray, *, pack: int, interpret: bool):
     from repro.core.lexbfs import lexbfs_inner_block
 
     n = adjs.shape[1]
@@ -96,7 +99,7 @@ def _fused_packed(adjs: jnp.ndarray, *, pack: int, interpret: bool = True):
 
 
 def lexbfs_peo_fused_packed(
-    adjs: jnp.ndarray, *, pack: int = 0, interpret: bool = True
+    adjs: jnp.ndarray, *, pack: int = 0, interpret: Optional[bool] = None
 ):
     """Packed tiny-bucket dispatch: G graphs per grid program.
 
@@ -115,5 +118,6 @@ def lexbfs_peo_fused_packed(
             [adjs, jnp.zeros((b_pad - b,) + adjs.shape[1:], adjs.dtype)],
             axis=0)
     dispatch_counter.tick()
-    verdicts, orders, viols = _fused_packed(adjs, pack=g, interpret=interpret)
+    verdicts, orders, viols = _fused_packed(
+        adjs, pack=g, interpret=resolve_interpret(interpret))
     return verdicts[:b], orders[:b], viols[:b]

@@ -6,13 +6,14 @@ intermediate in HBM: parents are computed by a blockwise argmax kernel, the
 parent rows ``Adj[p]`` are gathered once (XLA gather), and the violation
 count is a fused blockwise masked reduce.
 
-``interpret`` defaults to True (CPU-validated); on a real TPU deployment the
-wrapper is called with ``interpret=False`` and the same BlockSpecs compile
-via Mosaic.
+``interpret=None`` (default) resolves through
+:func:`repro.kernels.resolve_interpret`: interpreted on CPU hosts,
+compiled by Mosaic on a TPU.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -32,7 +33,7 @@ def peo_violations_count(
     *,
     block_v: int = 128,
     block_z: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     n = adj.shape[0]
     adj_i8 = adj.astype(jnp.int8)
@@ -60,7 +61,7 @@ def peo_check_pallas(
     *,
     block_v: int = 128,
     block_z: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """True iff ``order`` is a PEO of ``adj`` (Pallas-fused path)."""
     return (

@@ -108,11 +108,14 @@ def peo_violations_csr_numpy_batch(
     posz = pos[gid, cols]
     ln_e = posz < posu
     score = np.where(ln_e, posz, -1)
-    # Segment max over (graph, row): edges are segment-sorted => reduceat.
+    # Segment max over (graph, row): edges are segment-sorted => reduceat
+    # over the non-empty rows only (their starts are strictly increasing,
+    # so each segment ends where the next begins and the last at the end).
     off = np.concatenate([[0], np.cumsum(nnz)[:-1]])
     seg_starts = (row_ptr[:, :n].astype(np.int64) + off[:, None]).ravel()
-    p_pos = np.maximum.reduceat(score, np.minimum(seg_starts, total - 1))
-    p_pos[deg.ravel() == 0] = -1        # reduceat misreads empty segments
+    nonempty = deg.ravel() > 0
+    p_pos = np.full(b * n, -1, dtype=np.int64)
+    p_pos[nonempty] = np.maximum.reduceat(score, seg_starts[nonempty])
     p_pos = p_pos.reshape(b, n)
     p = orders.astype(np.int64)[
         np.arange(b)[:, None], np.clip(p_pos, 0, n - 1)]

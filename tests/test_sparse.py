@@ -38,6 +38,9 @@ def _zoo():
         G.gnp(26, 0.3, seed=4),
         G.clique(9),
         G.path(2),
+        # Last graph with edges: its final row's last edge must count in
+        # the host segment max although every row after it is empty.
+        G.gnp(19, 0.179, seed=3340),
         Graph(n_nodes=3),                 # empty graph, no arrays at all
     ]
 

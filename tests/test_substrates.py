@@ -84,10 +84,7 @@ def test_compressed_psum_error_feedback():
     """Residual carries quantization error to the next step (axis size 1:
     the numerics of the feedback loop, not the collective, is under test)."""
     from jax.sharding import Mesh, PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:  # moved out of experimental after jax 0.4.x
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from repro.optim.compression import compressed_psum_leaf
 
     mesh = Mesh(np.asarray(jax.devices()[:1]).reshape(1), ("pod",))

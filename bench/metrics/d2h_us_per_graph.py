@@ -1,0 +1,9 @@
+"""Device to host copy of a unit's answers (span ``d2h`` under
+``dispatch``) per graph answered, in us; 0 where the program records no
+such span."""
+from bench.stages import stage_seconds
+
+
+def read(run):
+    got = stage_seconds(run.spans, "d2h")
+    return None if got is None else got[0] * 1e6 / got[1]

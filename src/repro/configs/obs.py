@@ -18,7 +18,7 @@ class ObsConfig:
     trace: bool = False
     # JSONL sink path for span trees + events; opened append.
     trace_path: Optional[str] = None
-    # Wrap backend dispatches in jax.profiler.TraceAnnotation.
+    # Mirror every entered span as a jax.profiler.TraceAnnotation.
     jax_annotations: bool = False
 
     def __post_init__(self):

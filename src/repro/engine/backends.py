@@ -51,6 +51,20 @@ from typing import Callable, Dict, Optional, Tuple
 
 import numpy as np
 
+from repro import obs
+
+
+def _stage_end(x):
+    """End a stage span of a traced dispatch at a real synchronisation
+    point: with tracing on, wait until ``x`` (any pytree of arrays) is on
+    the device. With tracing off return ``x`` untouched, so an untraced
+    dispatch makes the same calls in the same order and adds no sync."""
+    if obs.tracing_enabled():
+        import jax
+
+        jax.block_until_ready(x)
+    return x
+
 
 @dataclasses.dataclass(frozen=True)
 class BackendCaps:
@@ -361,6 +375,15 @@ class PallasPeoBackend(ChordalityBackend):
     the same build is correct on CPU CI and compiles via Mosaic on TPU. ``caps.batched`` stays False: it describes the *split* batch
     contract; fused units are natively batched and keyed separately.
 
+    Each executable records child spans of the session's ``dispatch``
+    (DESIGN.md §15), each ended, when tracing is on, by a sync on its
+    output: ``pack`` (host int8 conversion), ``h2d`` (host→device copy
+    and relayout), ``device`` (launch until the outputs are ready),
+    ``d2h`` (device→host copy) and, on ``fused_witness``,
+    ``host_finalize`` (host assembly of the WitnessBatch). The split
+    pipeline records ``h2d``/``device``/``d2h`` once per graph and has no
+    ``pack``: its bool adjacency goes straight in.
+
     PR 6 adds two more compile-cache kinds (DESIGN.md §12):
 
     * ``fused_witness`` — the witness variant of the fused kernel emits
@@ -413,20 +436,29 @@ class PallasPeoBackend(ChordalityBackend):
         return ("fused_witness" if n_pad <= FUSED_WITNESS_MAX_NPAD
                 else "witness")
 
-    def compile_fused_batch(self, n_pad, batch):
+    def _fused_verdicts(self, kernel):
+        """Verdict executable over one call of a fused kernel
+        (``lexbfs_peo_fused`` or its packed variant)."""
         import jax.numpy as jnp
-
-        from repro.kernels.lexbfs_fused.ops import lexbfs_peo_fused
 
         interpret = self._interpret
 
         def run(adjs: np.ndarray) -> np.ndarray:
-            verdicts, _, _ = lexbfs_peo_fused(
-                jnp.asarray(np.asarray(adjs, dtype=np.int8)),
-                interpret=interpret)
-            return np.asarray(verdicts)
+            with obs.span("pack"):
+                adjs = np.asarray(adjs, dtype=np.int8)
+            with obs.span("h2d"):
+                x = _stage_end(jnp.asarray(adjs))
+            with obs.span("device"):
+                verdicts, _, _ = _stage_end(kernel(x, interpret=interpret))
+            with obs.span("d2h"):
+                return np.asarray(verdicts)
 
         return run
+
+    def compile_fused_batch(self, n_pad, batch):
+        from repro.kernels.lexbfs_fused.ops import lexbfs_peo_fused
+
+        return self._fused_verdicts(lexbfs_peo_fused)
 
     def compile_batch(self, n_pad, batch):
         import jax.numpy as jnp
@@ -440,10 +472,14 @@ class PallasPeoBackend(ChordalityBackend):
         def run(adjs: np.ndarray) -> np.ndarray:
             out = np.zeros(adjs.shape[0], dtype=bool)
             for i, adj in enumerate(adjs):
-                a = jnp.asarray(adj)
+                with obs.span("h2d"):
+                    a = _stage_end(jnp.asarray(adj))
                 dispatch_counter.tick(2)   # LexBFS jit + PEO kernel launch
-                out[i] = bool(
-                    peo_check_pallas(a, lexbfs(a), interpret=interpret))
+                with obs.span("device"):
+                    ok = _stage_end(
+                        peo_check_pallas(a, lexbfs(a), interpret=interpret))
+                with obs.span("d2h"):
+                    out[i] = bool(ok)
             return out
 
         return run
@@ -460,19 +496,9 @@ class PallasPeoBackend(ChordalityBackend):
         return viol == 0, np.asarray(order), viol
 
     def compile_fused_packed_batch(self, n_pad, batch):
-        import jax.numpy as jnp
-
         from repro.kernels.lexbfs_fused.ops import lexbfs_peo_fused_packed
 
-        interpret = self._interpret
-
-        def run(adjs: np.ndarray) -> np.ndarray:
-            verdicts, _, _ = lexbfs_peo_fused_packed(
-                jnp.asarray(np.asarray(adjs, dtype=np.int8)),
-                interpret=interpret)
-            return np.asarray(verdicts)
-
-        return run
+        return self._fused_verdicts(lexbfs_peo_fused_packed)
 
     def compile_fused_witness_batch(self, n_pad, batch):
         import jax.numpy as jnp
@@ -483,13 +509,18 @@ class PallasPeoBackend(ChordalityBackend):
         interpret = self._interpret
 
         def run(adjs, n_nodes):
-            adjs = np.asarray(adjs, dtype=bool)
-            _, orders, viols, ln, parent, triple = lexbfs_peo_fused_witness(
-                jnp.asarray(adjs.astype(np.int8)), interpret=interpret)
-            return witness_batch_from_fused_raw(
-                adjs, np.asarray(orders), np.asarray(viols),
-                np.asarray(ln), np.asarray(parent), np.asarray(triple),
-                n_nodes)
+            with obs.span("pack"):
+                adjs = np.asarray(adjs, dtype=bool)
+                a8 = adjs.astype(np.int8)
+            with obs.span("h2d"):
+                x = _stage_end(jnp.asarray(a8))
+            with obs.span("device"):
+                outs = _stage_end(
+                    lexbfs_peo_fused_witness(x, interpret=interpret))
+            with obs.span("d2h"):
+                raw = [np.asarray(o) for o in outs[1:]]
+            with obs.span("host_finalize"):
+                return witness_batch_from_fused_raw(adjs, *raw, n_nodes)
 
         return run
 

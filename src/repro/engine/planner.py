@@ -37,10 +37,10 @@ _M_CACHE_HITS = obs.registry.counter(
     "repro_compile_cache_hits_total", "compile-cache executable reuses")
 _M_CACHE_MISSES = obs.registry.counter(
     "repro_compile_cache_misses_total",
-    "compile-cache misses (each pays trace + compile)")
-_M_COMPILE_S = obs.registry.counter(
-    "repro_compile_seconds_total",
-    "wall seconds spent building executables on cache misses")
+    "compile-cache misses (each builds an executable)")
+# XLA compiles themselves happen at an executable's first call, not when
+# the cache builds it: they are counted by repro.obs.compiles.
+obs.install_compile_listener()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -200,9 +200,11 @@ class CompileCache:
     compile per bucket shape; the session picks the verdict family per
     bucket via ``backend.verdict_kind(n_pad)`` and the witness family
     via ``backend.witness_kind(n_pad)``. A
-    miss pays tracing + XLA compile for the device backends; a hit reuses
-    the executable. The hit/miss counters feed the engine's stats — in
-    steady-state serving, misses stay flat.
+    miss builds the executable; a device backend's XLA compile happens
+    at its first call (``repro_xla_compiles_total``,
+    :mod:`repro.obs.compiles`). A hit reuses the executable. The
+    hit/miss counters feed the engine's stats — in steady-state serving,
+    misses stay flat.
     """
 
     def __init__(self):
@@ -225,27 +227,21 @@ class CompileCache:
         if fn is None:
             self.misses += 1
             _M_CACHE_MISSES.inc()
-            with obs.span("compile", backend=backend.name, scope=scope,
-                          kind=kind, n_pad=n_pad, batch=batch) as sp:
-                t0 = obs.clock.now()
-                if kind == "verdict":
-                    fn = backend.compile_batch(n_pad, batch)
-                elif kind == "fused":
-                    fn = backend.compile_fused_batch(n_pad, batch)
-                elif kind == "fused_packed":
-                    fn = backend.compile_fused_packed_batch(n_pad, batch)
-                elif kind == "witness":
-                    fn = backend.compile_witness_batch(n_pad, batch)
-                elif kind == "fused_witness":
-                    fn = backend.compile_fused_witness_batch(n_pad, batch)
-                elif kind.startswith("recognition:"):
-                    props = tuple(kind[len("recognition:"):].split(","))
-                    fn = backend.compile_recognition_batch(
-                        n_pad, batch, props)
-                else:
-                    raise ValueError(f"unknown executable kind {kind!r}")
-                _M_COMPILE_S.inc(obs.clock.now() - t0)
-                sp.attrs["hit"] = False
+            if kind == "verdict":
+                fn = backend.compile_batch(n_pad, batch)
+            elif kind == "fused":
+                fn = backend.compile_fused_batch(n_pad, batch)
+            elif kind == "fused_packed":
+                fn = backend.compile_fused_packed_batch(n_pad, batch)
+            elif kind == "witness":
+                fn = backend.compile_witness_batch(n_pad, batch)
+            elif kind == "fused_witness":
+                fn = backend.compile_fused_witness_batch(n_pad, batch)
+            elif kind.startswith("recognition:"):
+                props = tuple(kind[len("recognition:"):].split(","))
+                fn = backend.compile_recognition_batch(n_pad, batch, props)
+            else:
+                raise ValueError(f"unknown executable kind {kind!r}")
             self._fns[key] = fn
         else:
             self.hits += 1

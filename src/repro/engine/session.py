@@ -379,10 +379,7 @@ class ChordalityEngine:
                 payload = self._realize(backend, unit, graphs)
             fn = self.cache.get(backend, unit.n_pad, unit.batch, kind=kind)
             t1 = _clock.now()
-            with obs.span("dispatch", backend=backend.name, kind=kind), \
-                    obs.trace_annotation(
-                        f"repro.dispatch/{backend.name}/{kind}"
-                        f"/n{unit.n_pad}b{unit.batch}"):
+            with obs.span("dispatch", backend=backend.name, kind=kind):
                 out = fn(payload)
             exec_ms = (_clock.now() - t1) * 1e3
         sample = (
@@ -420,10 +417,7 @@ class ChordalityEngine:
                 n_vec = self._unit_n_nodes(unit, graphs)
             fn = self.cache.get(backend, unit.n_pad, unit.batch, kind=kind)
             t1 = _clock.now()
-            with obs.span("dispatch", backend=backend.name, kind=kind), \
-                    obs.trace_annotation(
-                        f"repro.dispatch/{backend.name}/{kind}"
-                        f"/n{unit.n_pad}b{unit.batch}"):
+            with obs.span("dispatch", backend=backend.name, kind=kind):
                 wb = fn(payload, n_vec)
             exec_ms = (_clock.now() - t1) * 1e3
             with obs.span("finalize", kind="witness_crop"):
@@ -464,10 +458,7 @@ class ChordalityEngine:
                 n_vec = self._unit_n_nodes(unit, graphs)
             fn = self.cache.get(backend, unit.n_pad, unit.batch, kind=kind)
             t1 = _clock.now()
-            with obs.span("dispatch", backend=backend.name, kind=kind), \
-                    obs.trace_annotation(
-                        f"repro.dispatch/{backend.name}/{kind}"
-                        f"/n{unit.n_pad}b{unit.batch}"):
+            with obs.span("dispatch", backend=backend.name, kind=kind):
                 rb = fn(payload, n_vec)
             exec_ms = (_clock.now() - t1) * 1e3
             with obs.span("finalize", kind="recognition_crop"):
@@ -656,10 +647,8 @@ class ChordalityEngine:
         Returns the engine's compile-cache traffic (with hit ratio), the
         router-calibration sample count, and the process-global metrics
         registry snapshot (which the cache and kernel counters publish
-        into). The VMEM-plan gauges are refreshed on every call so the
-        snapshot always carries the current static budget table.
+        into).
         """
-        obs.publish_vmem_plan()
         hits, misses = self.cache.hits, self.cache.misses
         total = hits + misses
         return {

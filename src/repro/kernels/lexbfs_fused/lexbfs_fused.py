@@ -237,7 +237,8 @@ def _visit_kernel(n, g, k_inner, u_block, witness, adj_ref, order_ref,
     jax.lax.fori_loop(0, n, step, 0)
 
 
-def _visit_call(adj_i8, *, pack, k_inner, u_block, witness, interpret):
+def _visit_call(adj_i8, *, name, pack, k_inner, u_block, witness,
+                interpret):
     from jax.experimental.pallas import tpu as pltpu
 
     b, n = adj_i8.shape[0], adj_i8.shape[1]
@@ -262,6 +263,7 @@ def _visit_call(adj_i8, *, pack, k_inner, u_block, witness, interpret):
         ]
     outs = pl.pallas_call(
         kernel,
+        name=name,
         grid=(groups,),
         in_specs=[pl.BlockSpec((pack, n, n), lambda i: (i, 0, 0))],
         out_specs=out_specs,
@@ -281,8 +283,9 @@ def _visit_call(adj_i8, *, pack, k_inner, u_block, witness, interpret):
 def lexbfs_peo_fused_call(adj_i8, *, k_inner: int, u_block: int,
                           interpret: Optional[bool] = None):
     """Raw pallas_call: (B, N, N) int8 -> (orders (B, N), viols (B, 1))."""
-    return _visit_call(adj_i8, pack=1, k_inner=k_inner, u_block=u_block,
-                       witness=False, interpret=interpret)
+    return _visit_call(adj_i8, name="lexbfs_peo_fused", pack=1,
+                       k_inner=k_inner, u_block=u_block, witness=False,
+                       interpret=interpret)
 
 
 def lexbfs_peo_fused_witness_call(adj_i8, *, k_inner: int, u_block: int,
@@ -290,8 +293,9 @@ def lexbfs_peo_fused_witness_call(adj_i8, *, k_inner: int, u_block: int,
     """Raw pallas_call: (B, N, N) int8 ->
     (orders (B, N), viols (B, 1), ln (B, N, N) i8, parent (B, N),
     triple (B, 3))."""
-    return _visit_call(adj_i8, pack=1, k_inner=k_inner, u_block=u_block,
-                       witness=True, interpret=interpret)
+    return _visit_call(adj_i8, name="lexbfs_peo_fused_witness", pack=1,
+                       k_inner=k_inner, u_block=u_block, witness=True,
+                       interpret=interpret)
 
 
 def lexbfs_peo_fused_packed_call(adj_i8, *, pack: int, k_inner: int,
@@ -302,5 +306,6 @@ def lexbfs_peo_fused_packed_call(adj_i8, *, pack: int, k_inner: int,
     B must be a multiple of ``pack`` (the public wrapper pads with empty
     graphs). Outputs match :func:`lexbfs_peo_fused_call` exactly.
     """
-    return _visit_call(adj_i8, pack=pack, k_inner=k_inner, u_block=u_block,
-                       witness=False, interpret=interpret)
+    return _visit_call(adj_i8, name="lexbfs_peo_fused_packed", pack=pack,
+                       k_inner=k_inner, u_block=u_block, witness=False,
+                       interpret=interpret)

@@ -89,6 +89,7 @@ def peo_parents_pallas(
     ]
     best_pos, p = pl.pallas_call(
         functools.partial(_parent_kernel, n),
+        name="peo_check_best",
         grid=(nv, nz),
         in_specs=[
             pl.BlockSpec((block_v, block_z), lambda i, j: (i, j)),
@@ -154,6 +155,7 @@ def peo_violations_pallas(
     nv, nz = pl.cdiv(n, block_v), pl.cdiv(n, block_z)
     count = pl.pallas_call(
         functools.partial(_violation_kernel, n),
+        name="peo_check_count",
         grid=(nv, nz),
         in_specs=[
             pl.BlockSpec((block_v, block_z), lambda i, j: (i, j)),

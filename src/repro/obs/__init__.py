@@ -11,7 +11,9 @@ The serving stack's telemetry layer (DESIGN.md §15):
   service stats;
 * :mod:`repro.obs.export` — JSONL sink + Prometheus text render;
 * :mod:`repro.obs.profiling` — opt-in ``jax.profiler`` annotations
-  around dispatches.
+  mirroring every entered span;
+* :mod:`repro.obs.compiles` — the XLA compile counters and
+  ``xla_compile`` spans, from one ``jax.monitoring`` listener.
 
 Quick start::
 
@@ -24,13 +26,14 @@ Quick start::
     print(obs.render_prometheus())
 """
 from repro.obs import clock  # noqa: F401  (re-exported submodule)
+from repro.obs.compiles import install_compile_listener
 from repro.obs.export import (JsonlSink, ListSink, parse_jsonl,
                               render_prometheus, span_from_dict)
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                registry)
 from repro.obs.profiling import (disable_jax_annotations,
                                  enable_jax_annotations,
-                                 jax_annotations_enabled, trace_annotation)
+                                 jax_annotations_enabled)
 from repro.obs.trace import (NOOP_SPAN, Span, Tracer, event, get_tracer,
                              span, tracer)
 
@@ -39,9 +42,9 @@ __all__ = [
     "span", "event", "enable_tracing", "disable_tracing",
     "tracing_enabled", "Counter", "Gauge", "Histogram",
     "MetricsRegistry", "registry", "render_prometheus", "JsonlSink",
-    "ListSink", "parse_jsonl", "span_from_dict", "trace_annotation",
+    "ListSink", "parse_jsonl", "span_from_dict",
     "enable_jax_annotations", "disable_jax_annotations",
-    "jax_annotations_enabled", "publish_vmem_plan", "configure",
+    "jax_annotations_enabled", "install_compile_listener", "configure",
 ]
 
 
@@ -57,32 +60,6 @@ def disable_tracing() -> None:
 
 def tracing_enabled() -> bool:
     return tracer.enabled
-
-
-def publish_vmem_plan() -> None:
-    """Publish the static VMEM plan as gauges: per engine bucket, the
-    fused-kernel working set (``repro_fused_vmem_bytes``) and remaining
-    headroom against ``TPU_VMEM_BYTES`` — negative headroom is exactly
-    why ``verdict_kind`` falls back to the split pipeline above
-    ``FUSED_MAX_NPAD``."""
-    from repro.configs import shapes
-
-    g_bytes = registry.gauge(
-        "repro_fused_vmem_bytes",
-        "fused-kernel VMEM working set per n_pad bucket", labels=("n_pad",))
-    g_headroom = registry.gauge(
-        "repro_fused_vmem_headroom_bytes",
-        "TPU_VMEM_BYTES minus fused working set (negative = split path)",
-        labels=("n_pad",))
-    g_wit = registry.gauge(
-        "repro_fused_witness_vmem_bytes",
-        "fused witness-kernel VMEM working set per n_pad bucket",
-        labels=("n_pad",))
-    for n_pad in shapes.ENGINE_NPAD_BUCKETS:
-        b = shapes.fused_vmem_bytes(n_pad)
-        g_bytes.set(b, n_pad=n_pad)
-        g_headroom.set(shapes.TPU_VMEM_BYTES - b, n_pad=n_pad)
-        g_wit.set(shapes.fused_witness_vmem_bytes(n_pad), n_pad=n_pad)
 
 
 def configure(cfg) -> None:

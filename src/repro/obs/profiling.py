@@ -1,18 +1,16 @@
 """Opt-in bridge from obs spans to ``jax.profiler`` trace annotations.
 
-When enabled (``enable_jax_annotations()``), :func:`trace_annotation`
-wraps each backend dispatch in a ``jax.profiler.TraceAnnotation`` so the
-named interval shows up on the device timeline of a captured profile —
-letting our host-side ``dispatch`` spans line up with the XLA/TPU trace.
-Disabled (the default) it returns a shared null context: no jax import
-cost, no profiler dependency on the hot path.
+When enabled (``enable_jax_annotations()``), every span entered as a
+context manager (``with obs.span(...)``) also enters a
+``jax.profiler.TraceAnnotation`` of the same name and attributes, so the
+span's interval shows up on the host timeline of a captured profile, on
+the device trace's own clock. Annotations ride on spans: with tracing
+off there is no span and so no annotation. Disabled (the default) no
+jax import happens and a span's enter/exit pays one flag read.
 """
 from __future__ import annotations
 
-import contextlib
-
 _jax_annotations_enabled = False
-_NULL = contextlib.nullcontext()
 
 
 def enable_jax_annotations() -> None:
@@ -29,14 +27,9 @@ def jax_annotations_enabled() -> bool:
     return _jax_annotations_enabled
 
 
-def trace_annotation(name: str):
-    """Context manager for a device-profile annotation around a dispatch.
-    A null context unless annotations are enabled and jax's profiler is
-    importable."""
-    if not _jax_annotations_enabled:
-        return _NULL
-    try:
-        from jax.profiler import TraceAnnotation
-    except Exception:  # pragma: no cover - jax always present in-tree
-        return _NULL
-    return TraceAnnotation(name)
+def annotation(name: str, attrs: dict):
+    """An entered-by-the-caller ``TraceAnnotation`` for one span; its
+    attributes become the profiler event's stats."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(name, **attrs)

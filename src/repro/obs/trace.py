@@ -3,7 +3,8 @@
 A :class:`Span` is a named ``[t_start, t_end]`` interval with attributes
 and children; a finished request carries one closed span *tree* (root
 ``request`` with ``queue``/``plan``/``exec``(→``unit``→``realize``/
-``compile``/``dispatch``)/``finalize`` stages — see DESIGN.md §15).
+``dispatch``(→ backend stages ``pack``/``h2d``/``device``/``d2h``/
+``host_finalize``))/``finalize`` stages — see DESIGN.md §15).
 
 Design points:
 
@@ -22,6 +23,9 @@ Design points:
   stage sum equals the root duration exactly.
 * **Timestamps** come from :mod:`repro.obs.clock` (one monotonic clock
   for deadlines, waits, and spans alike).
+* **Profiler timeline.** With :func:`repro.obs.enable_jax_annotations`
+  on, an entered span also enters a ``jax.profiler.TraceAnnotation`` of
+  its name and attributes (:mod:`repro.obs.profiling`).
 """
 from __future__ import annotations
 
@@ -29,11 +33,12 @@ import threading
 from typing import Any, Dict, List, Optional
 
 from repro.obs import clock as _clock
+from repro.obs import profiling as _profiling
 
 
 class Span:
     __slots__ = ("name", "attrs", "t_start", "t_end", "children",
-                 "_parent", "_tracer", "_emit")
+                 "_parent", "_tracer", "_emit", "_annotation")
 
     def __init__(self, name: str, attrs: Optional[Dict[str, Any]] = None,
                  t_start: Optional[float] = None, tracer=None,
@@ -46,14 +51,21 @@ class Span:
         self._parent = parent
         self._tracer = tracer
         self._emit = emit
+        self._annotation = None
 
     # -- lifecycle -----------------------------------------------------
     def __enter__(self) -> "Span":
         if self._tracer is not None:
             self._tracer._stack().append(self)
+        if _profiling.jax_annotations_enabled():
+            self._annotation = _profiling.annotation(self.name, self.attrs)
+            self._annotation.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
         if self._tracer is not None:
             stack = self._tracer._stack()
             if stack and stack[-1] is self:
@@ -214,6 +226,17 @@ class Tracer:
         return Span(name, attrs, t_start=t, tracer=self, parent=None,
                     emit=False)
 
+    def attach(self, name: str, t_start: float, t_end: float,
+               **attrs) -> None:
+        """Add a closed ``[t_start, t_end]`` child to the calling thread's
+        innermost open span (no-op when disabled or none is open): an
+        interval measured by someone else, such as an XLA compile."""
+        if not self._enabled:
+            return
+        stack = self._stack()
+        if stack:
+            stack[-1].child(name, t=t_start, **attrs).end(t_end)
+
     def event(self, name: str, **attrs) -> None:
         """Point event (autotune decision, router refit) → sink."""
         if not self._enabled or self._sink is None:
@@ -248,7 +271,7 @@ def get_tracer() -> Tracer:
 
 
 def span(name: str, **attrs):
-    """Module-level convenience: ``with obs.span("compile", n_pad=...):``"""
+    """Module-level convenience: ``with obs.span("realize", n_pad=...):``"""
     return tracer.span(name, **attrs)
 
 

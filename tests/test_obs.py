@@ -178,16 +178,6 @@ def test_dispatch_and_sweep_counters_thread_safe_and_registry_backed():
     assert {"repro_dispatches_total", "repro_sweeps_total"} <= names
 
 
-def test_vmem_plan_gauges_match_shapes_module():
-    from repro.configs import shapes
-
-    obs.publish_vmem_plan()
-    snap = obs.registry.snapshot()["repro_fused_vmem_bytes"]["series"]
-    by_npad = {int(s["labels"]["n_pad"]): s["value"] for s in snap}
-    for n_pad in shapes.ENGINE_NPAD_BUCKETS:
-        assert by_npad[n_pad] == shapes.fused_vmem_bytes(n_pad)
-
-
 # ---------------------------------------------------------------------------
 # Span mechanics: nesting, noop cheapness, manual stitching.
 # ---------------------------------------------------------------------------
@@ -359,6 +349,149 @@ def test_sync_engine_traces_unit_trees():
 
 
 # ---------------------------------------------------------------------------
+# Backend stages: pallas_peo splits its dispatch into pack / h2d / device /
+# d2h (and host_finalize on certified units), synced only when tracing.
+# ---------------------------------------------------------------------------
+_STAGES = {"fused": ["pack", "h2d", "device", "d2h"],
+           "fused_packed": ["pack", "h2d", "device", "d2h"],
+           "verdict": ["h2d", "device", "d2h"],
+           "fused_witness": ["pack", "h2d", "device", "d2h",
+                             "host_finalize"]}
+
+
+def _traced_units(graphs, pipeline, **run_kw):
+    sink = obs.ListSink()
+    obs.enable_tracing(sink)
+    eng = ChordalityEngine(backend="pallas_peo", max_batch=2,
+                           pipeline=pipeline, interpret=True)
+    res = eng.run(graphs, **run_kw)
+    obs.disable_tracing()
+    return res, [s for s in sink.spans if s.name == "unit"]
+
+
+def _assert_stages_partition(unit):
+    (dispatch,) = [c for c in unit.children if c.name == "dispatch"]
+    stages = dispatch.children
+    per_graph = _STAGES[unit.attrs["kind"]]
+    sets = unit.attrs["batch"] if unit.attrs["kind"] == "verdict" else 1
+    assert [s.name for s in stages] == per_graph * sets
+    t = dispatch.t_start
+    for s in stages:                       # in order, disjoint, inside
+        assert s.closed and t <= s.t_start <= s.t_end
+        t = s.t_end
+    assert t <= dispatch.t_end
+
+
+@pytest.mark.parametrize("pipeline,n,kind", [
+    ("fused", 9, "fused_packed"),
+    ("fused", 70, "fused"),
+    ("split", 9, "verdict"),
+])
+def test_stage_spans_partition_dispatch(pipeline, n, kind):
+    graphs = [G.cycle(n), G.clique(n)]
+    res, units = _traced_units(graphs, pipeline)
+    assert list(res.verdicts) == [n <= 3, True]
+    assert units and {u.attrs["kind"] for u in units} == {kind}
+    for u in units:
+        _assert_stages_partition(u)
+
+
+def test_witness_stage_spans_end_with_host_finalize():
+    res, units = _traced_units([G.cycle(9), G.random_chordal(12, k=3,
+                                                             seed=0)],
+                               "fused", witness=True)
+    assert list(res.verdicts) == [False, True]
+    assert units and {u.attrs["kind"] for u in units} == {"fused_witness"}
+    for u in units:
+        _assert_stages_partition(u)
+
+
+@pytest.mark.parametrize("pipeline", ["fused", "split"])
+def test_untraced_dispatch_adds_no_sync(monkeypatch, pipeline):
+    import jax
+
+    calls = []
+    real = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: calls.append(1) or real(x))
+    eng = ChordalityEngine(backend="pallas_peo", max_batch=2,
+                           pipeline=pipeline, interpret=True)
+    graphs = [G.cycle(9), G.clique(9)]
+    eng.run(graphs)
+    assert calls == []                      # tracing off: no added sync
+    obs.enable_tracing(obs.ListSink())
+    eng.run(graphs)
+    obs.disable_tracing()
+    assert calls                            # the patch does see the syncs
+
+
+# ---------------------------------------------------------------------------
+# XLA compiles: counted by one jax.monitoring listener, not by the cache.
+# ---------------------------------------------------------------------------
+def _compile_counters():
+    return (obs.registry.counter("repro_xla_compiles_total"),
+            obs.registry.counter("repro_compile_seconds_total"))
+
+
+def test_xla_compile_listener_counts_and_attaches_span():
+    import jax
+    import jax.numpy as jnp
+
+    obs.install_compile_listener()
+    obs.install_compile_listener()          # idempotent
+    n_c, s_c = _compile_counters()
+    x = jnp.arange(7)
+    x.block_until_ready()
+    n0, s0 = n_c.total(), s_c.total()
+    sink = obs.ListSink()
+    obs.enable_tracing(sink)
+
+    def fresh_program_for_this_test(v):
+        return v * 3 + 11
+
+    f = jax.jit(fresh_program_for_this_test)
+    with obs.span("outer"):
+        f(x).block_until_ready()
+    n1 = n_c.total()
+    with obs.span("outer"):
+        f(x).block_until_ready()            # cached call: no compile
+    obs.disable_tracing()
+    first, second = sink.spans
+    compiles = [c for c in first.children if c.name == "xla_compile"]
+    assert len(compiles) == n1 - n0 >= 1
+    assert s_c.total() > s0
+    assert any("fresh_program_for_this_test" in c.attrs["fun_name"]
+               for c in compiles)
+    for c in compiles:
+        assert c.closed and not c.attrs["cache_load"]
+        assert first.t_start <= c.t_start <= c.t_end <= first.t_end
+    assert n_c.total() == n1
+    assert not [c for c in second.children if c.name == "xla_compile"]
+
+
+def test_persistent_cache_load_is_not_counted_as_compile():
+    import jax.monitoring
+
+    from repro.obs import compiles
+
+    obs.install_compile_listener()
+    n_c, s_c = _compile_counters()
+    n0, s0 = n_c.total(), s_c.total()
+    sink = obs.ListSink()
+    obs.enable_tracing(sink)
+    with obs.span("outer"):
+        jax.monitoring.record_event_duration_secs(
+            compiles.CACHE_RETRIEVAL_EVENT, 0.001)
+        jax.monitoring.record_event_duration_secs(
+            compiles.BACKEND_COMPILE_EVENT, 0.002, fun_name="loaded")
+    obs.disable_tracing()
+    assert (n_c.total(), s_c.total()) == (n0, s0)
+    (span,) = sink.spans[0].children
+    assert span.name == "xla_compile" and span.attrs == {
+        "fun_name": "loaded", "cache_load": True}
+
+
+# ---------------------------------------------------------------------------
 # Export: JSONL round-trip + Prometheus scraper grammar.
 # ---------------------------------------------------------------------------
 def test_span_dict_round_trip_is_identity():
@@ -417,7 +550,8 @@ def test_prometheus_render_matches_scraper_grammar():
             config=ServiceConfig(max_batch=4, max_wait_ms=1.0),
             backend="numpy_ref") as svc:
         gather(svc.submit_many([G.cycle(9)]), timeout=60)
-    obs.publish_vmem_plan()
+    obs.registry.gauge("test_render_gauge_bytes", "a labelled gauge",
+                       labels=("n_pad",)).set(1024, n_pad=64)
     text = obs.render_prometheus()
     assert "repro_requests_total" in text
     assert "repro_queue_delay_ms_bucket" in text
@@ -434,7 +568,7 @@ def test_prometheus_render_matches_scraper_grammar():
         assert _PROM_SAMPLE.match(line), f"unscrapeable line: {line!r}"
     assert kinds["repro_requests_total"] == "counter"
     assert kinds["repro_queue_delay_ms"] == "histogram"
-    assert kinds["repro_fused_vmem_bytes"] == "gauge"
+    assert kinds["test_render_gauge_bytes"] == "gauge"
 
 
 # ---------------------------------------------------------------------------
@@ -469,16 +603,29 @@ def test_engine_and_service_telemetry_shapes():
     assert stel["units"]["executed"] >= 1
 
 
-def test_profiling_bridge_is_nullcontext_when_disabled():
+def test_profiling_bridge_is_nullcontext_when_disabled(monkeypatch):
+    """Annotations ride on entered spans: none while disabled, one
+    ``TraceAnnotation`` per entered span, with its attrs, once enabled."""
+    from repro.obs import profiling
+
+    made = []
+    real = profiling.annotation
+    monkeypatch.setattr(profiling, "annotation",
+                        lambda name, attrs: made.append((name, dict(attrs)))
+                        or real(name, attrs))
+    obs.enable_tracing(obs.ListSink())
     obs.disable_jax_annotations()
-    with obs.trace_annotation("repro.dispatch/test"):
+    with obs.span("dispatch", backend="b"):
         pass                                # no jax import, no effect
+    assert made == []
     obs.enable_jax_annotations()
     try:
-        with obs.trace_annotation("repro.dispatch/test"):
-            pass                            # real TraceAnnotation path
+        with obs.span("dispatch", backend="b"):
+            with obs.span("h2d"):
+                pass                        # real TraceAnnotation path
     finally:
         obs.disable_jax_annotations()
+    assert made == [("dispatch", {"backend": "b"}), ("h2d", {})]
 
 
 # ---------------------------------------------------------------------------

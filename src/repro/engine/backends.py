@@ -54,6 +54,32 @@ import numpy as np
 from repro import obs
 
 
+# Fused dispatches whose ``pack`` stage had to copy the unit: zero while
+# every unit arrives from ``realize_unit`` as a numpy bool array.
+_M_PACK_COPIES = obs.registry.counter(
+    "repro_pack_copies_total",
+    "fused dispatches whose pack stage copied a unit that was not numpy "
+    "bool")
+
+
+def _pack(adjs):
+    """The ``pack`` stage of a fused dispatch: ``(bool_adjs, int8_adjs)``.
+
+    A numpy bool array is handed on as it is, with an int8 view of its own
+    bytes: both dtypes take one byte, the jitted wrappers cast to int8
+    anyway and the kernels read an entry only as ``!= 0``, so the device
+    gets the same program and the same bytes without a host copy. Any other
+    input (another dtype, a nested list) is converted to bool first, one
+    copy, counted in ``repro_pack_copies_total`` and marked ``copied`` on
+    the span."""
+    copied = not (isinstance(adjs, np.ndarray) and adjs.dtype == np.bool_)
+    with obs.span("pack", copied=copied):
+        if copied:
+            _M_PACK_COPIES.inc()
+            adjs = np.asarray(adjs, dtype=bool)
+        return adjs, adjs.view(np.int8)
+
+
 def _stage_end(x):
     """End a stage span of a traced dispatch at a real synchronisation
     point: with tracing on, wait until ``x`` (any pytree of arrays) is on
@@ -377,12 +403,14 @@ class PallasPeoBackend(ChordalityBackend):
 
     Each executable records child spans of the session's ``dispatch``
     (DESIGN.md §15), each ended, when tracing is on, by a sync on its
-    output: ``pack`` (host int8 conversion), ``h2d`` (host→device copy
-    and relayout), ``device`` (launch until the outputs are ready),
-    ``d2h`` (device→host copy) and, on ``fused_witness``,
-    ``host_finalize`` (host assembly of the WitnessBatch). The split
-    pipeline records ``h2d``/``device``/``d2h`` once per graph and has no
-    ``pack``: its bool adjacency goes straight in.
+    output: ``pack`` (the unit as int8: a zero-copy view of a bool
+    array, else a counted conversion, see :func:`_pack`), ``h2d``
+    (host→device copy and relayout), ``device`` (launch until the
+    outputs are ready), ``d2h`` (device→host copy) and, on
+    ``fused_witness``, ``host_finalize`` (host assembly of the
+    WitnessBatch). The split pipeline records ``h2d``/``device``/``d2h``
+    once per graph and has no ``pack``: its bool adjacency goes straight
+    in.
 
     PR 6 adds two more compile-cache kinds (DESIGN.md §12):
 
@@ -444,10 +472,9 @@ class PallasPeoBackend(ChordalityBackend):
         interpret = self._interpret
 
         def run(adjs: np.ndarray) -> np.ndarray:
-            with obs.span("pack"):
-                adjs = np.asarray(adjs, dtype=np.int8)
+            _, a8 = _pack(adjs)
             with obs.span("h2d"):
-                x = _stage_end(jnp.asarray(adjs))
+                x = _stage_end(jnp.asarray(a8))
             with obs.span("device"):
                 verdicts, _, _ = _stage_end(kernel(x, interpret=interpret))
             with obs.span("d2h"):
@@ -509,9 +536,7 @@ class PallasPeoBackend(ChordalityBackend):
         interpret = self._interpret
 
         def run(adjs, n_nodes):
-            with obs.span("pack"):
-                adjs = np.asarray(adjs, dtype=bool)
-                a8 = adjs.astype(np.int8)
+            adjs, a8 = _pack(adjs)
             with obs.span("h2d"):
                 x = _stage_end(jnp.asarray(a8))
             with obs.span("device"):

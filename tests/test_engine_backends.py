@@ -83,3 +83,59 @@ def test_batch_padding_slots_do_not_leak(backend):
     res = ChordalityEngine(backend=backend, max_batch=4).run(graphs)
     assert res.plan.units[0].n_padding_slots == 1
     assert res.verdicts.tolist() == [False, True, False]
+
+
+# ---------------------------------------------------------------------------
+# pallas_peo's fused runners: a realized bool unit goes to the device as an
+# int8 view of its own bytes; anything else is converted once and counted.
+# ---------------------------------------------------------------------------
+_PACK_N = {"fused": 70, "fused_packed": 9, "fused_witness": 9}
+
+
+@pytest.mark.parametrize("case", ["bool", "int64", "list", "noncanonical"])
+@pytest.mark.parametrize("kind", sorted(_PACK_N))
+def test_fused_pack_views_bool_units(monkeypatch, kind, case):
+    import jax.numpy as jnp
+
+    from repro import obs
+    from repro.core.lexbfs import lexbfs_numpy_dense
+    from repro.core.peo import peo_check_numpy
+    from repro.engine.backends import PallasPeoBackend
+    from repro.engine.planner import plan_requests, realize_unit
+
+    n = _PACK_N[kind]
+    graphs = [G.cycle(n), G.random_chordal(n, k=3, seed=0)]
+    (unit,) = plan_requests(graphs, max_batch=2).units
+    realized = realize_unit(unit, graphs)
+    backend = PallasPeoBackend(interpret=True, pipeline="fused")
+    assert kind == (backend.witness_kind(unit.n_pad) if kind == "fused_witness"
+                    else backend.verdict_kind(unit.n_pad))
+    run = getattr(backend, f"compile_{kind}_batch")(unit.n_pad, unit.batch)
+    payload = {"bool": realized,
+               "int64": realized.astype(np.int64),
+               "list": realized.tolist(),
+               "noncanonical": (realized.view(np.uint8) * 2).view(bool)}[case]
+
+    sent = []
+    real_asarray = jnp.asarray
+    monkeypatch.setattr(jnp, "asarray",
+                        lambda x, *a, **k: sent.append(x) or
+                        real_asarray(x, *a, **k))
+    copies = obs.registry.counter("repro_pack_copies_total")
+    before = copies.value()
+    if kind == "fused_witness":
+        out = run(payload, np.array([g.n_nodes for g in graphs])).chordal
+    else:
+        out = run(payload)
+
+    copied = case in ("int64", "list")
+    assert copies.value() - before == int(copied)
+    h2d = sent[0]                          # the first copy to the device
+    assert h2d.dtype == np.int8
+    if case == "bool":
+        assert np.shares_memory(h2d, realized)
+    if case == "int64":
+        assert not np.shares_memory(h2d, payload)
+    want = [peo_check_numpy(a, lexbfs_numpy_dense(a)) for a in realized]
+    assert want == [n <= 3, True]
+    assert np.asarray(out).tolist() == want

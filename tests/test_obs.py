@@ -380,6 +380,9 @@ def _assert_stages_partition(unit):
         assert s.closed and t <= s.t_start <= s.t_end
         t = s.t_end
     assert t <= dispatch.t_end
+    # A realized unit is a bool array: pack views it, copying nothing.
+    assert all(s.attrs["copied"] is False for s in stages
+               if s.name == "pack")
 
 
 @pytest.mark.parametrize("pipeline,n,kind", [

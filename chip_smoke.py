@@ -20,9 +20,10 @@ Phases (one chip):
   backends (pallas_peo's fused witness kernel).
 
 With ``--chips 4`` only the ``sharded`` backend runs, on a mesh over four
-chips at n_pad 256 (B=32) and 2048 (B=8), compared with ``jax_fast`` on
-one chip and with ``numpy_ref``; its compiled program must hold no
-collective.
+chips at n_pad 256 (B=32) and 2048 (B=8), where it runs the fused Pallas
+kernel once per chip, and 4096 (B=4), where it runs ``jax_fast``'s
+program; it is compared with ``jax_fast`` on one chip and with
+``numpy_ref``, and neither compiled program may hold a collective.
 
 Lines before the last are informational (compile and wall seconds are
 set-up and smoke timings, not measured speed). The last line is
@@ -44,7 +45,7 @@ ROOT = pathlib.Path(__file__).resolve().parent
 #: bucket so every request pads to exactly that n_pad.
 VERDICT_BUCKETS = ((64, 32), (256, 32), (2048, 8), (8192, 2))
 WITNESS_BUCKETS = ((256, 8), (1024, 4))
-SHARDED_BUCKETS = ((256, 32), (2048, 8))
+SHARDED_BUCKETS = ((256, 32), (2048, 8), (4096, 4))
 COLLECTIVES = ("all-gather", "all-reduce", "collective-permute",
                "all-to-all", "reduce-scatter")
 
@@ -196,21 +197,36 @@ def check_pallas_paths(keys, backend) -> None:
                   f"no fused_witness executable at {n_pad}")
 
 
-def sharded_phase(device_kind, buckets=SHARDED_BUCKETS) -> None:
+def sharded_phase(device_kind, buckets=SHARDED_BUCKETS):
     """The sharded backend on a mesh over every chip vs jax_fast on one
-    chip vs numpy_ref; the partitioned program holds no collective."""
+    chip vs numpy_ref; the program each bucket's kind compiles holds no
+    collective. Returns the sharded phase's compile-cache keys and
+    backend for :func:`check_sharded_paths`."""
     import jax
+    import jax.numpy as jnp
     import numpy as np
 
-    from repro.engine.mesh import build_mesh, make_mesh_verdicts
+    from repro.engine.backends import make_backend
+    from repro.engine.mesh import (
+        batch_sharding,
+        build_mesh,
+        make_mesh_fused,
+        make_mesh_verdicts,
+    )
 
-    fn = make_mesh_verdicts(build_mesh())
+    mesh = build_mesh()
+    probe = make_backend("sharded", mesh=mesh)
     for n_pad, batch in buckets:
+        if probe.verdict_kind(n_pad) == "fused":
+            fn, dtype = make_mesh_fused(mesh, probe.interpret), jnp.int8
+        else:
+            fn, dtype = make_mesh_verdicts(mesh), jnp.bool_
         hlo = fn.lower(jax.ShapeDtypeStruct(
-            (batch, n_pad, n_pad), np.bool_)).compile().as_text()
+            (batch, n_pad, n_pad), dtype,
+            sharding=batch_sharding(mesh))).compile().as_text()
         found = [c for c in COLLECTIVES if c in hlo]
         check(not found, f"sharded n_pad={n_pad}: collectives {found}")
-    sharded, _, backend = run_phase("sharded", buckets, device_kind)
+    sharded, keys, backend = run_phase("sharded", buckets, device_kind)
     check(backend.device_count == len(jax.devices()),
           f"sharded mesh spans {backend.device_count} devices")
     one_chip, _, _ = run_phase("jax_fast", buckets, device_kind)
@@ -218,6 +234,22 @@ def sharded_phase(device_kind, buckets=SHARDED_BUCKETS) -> None:
         check(np.array_equal(sharded[n_pad], one_chip[n_pad]),
               f"sharded vs jax_fast (1 chip) n_pad={n_pad}: "
               f"{sharded[n_pad].tolist()} != {one_chip[n_pad].tolist()}")
+    return keys, backend
+
+
+def check_sharded_paths(keys, backend, buckets=SHARDED_BUCKETS) -> None:
+    """sharded on the chip: the Mosaic-compiled fused kernel per shard up
+    to the fused cap, the jax_fast program above it."""
+    from repro.configs.shapes import FUSED_MAX_NPAD
+
+    check(backend.interpret is False,
+          "sharded resolved interpret=True on a TPU")
+    kinds = {(k[2], k[3]) for k in keys}
+    for n_pad, _ in buckets:
+        want = "fused" if n_pad <= FUSED_MAX_NPAD else "verdict"
+        kind = backend.verdict_kind(n_pad)
+        check(kind == want, f"sharded n_pad={n_pad} served by {kind!r}")
+        check((kind, n_pad) in kinds, f"no {kind!r} executable at {n_pad}")
 
 
 def main(argv=None) -> int:
@@ -251,7 +283,7 @@ def main(argv=None) -> int:
     t0 = time.perf_counter()
     try:
         if args.chips == 4:
-            sharded_phase(kind)
+            check_sharded_paths(*sharded_phase(kind))
         else:
             for name in ("jax_fast", "pallas_peo"):
                 _, keys, _ = run_phase(name, VERDICT_BUCKETS, kind)

@@ -5,11 +5,13 @@ device; the engine generalized that to batched buckets (one compiled
 program per ``(n_pad, batch)`` shape). This module adds the third axis —
 *many devices* — without touching the kernels: a planner work unit's
 batch dimension is split across an explicit 1-D device mesh by a
-batch-axis ``NamedSharding``, each shard holding whole graphs (adjacency tiles are
-never split across devices), and the per-shard math is exactly the
-``jax_fast`` verdict pipeline. Verdicts are therefore bit-identical to
-the single-device backends at every mesh size, and one jit dispatch per
-work unit drives every shard (DESIGN.md §16).
+batch-axis ``NamedSharding``, each shard holding whole graphs (adjacency
+tiles are never split across devices). The per-shard program is the one
+a single chip runs at the same bucket: the fused LexBFS+PEO Pallas kernel
+at n_pad <= ``FUSED_MAX_NPAD`` on TPUs, else the ``jax_fast`` verdict
+pipeline. Verdicts are therefore bit-identical to the single-device
+backends at every mesh size, and one jit dispatch per work unit drives
+every shard (DESIGN.md §16).
 
 CPU CI exercises real multi-device partitioning by emulating host
 devices: ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` **before
@@ -25,12 +27,18 @@ Surface:
   platform + device slice an executable is pinned to; the compile
   cache's scope component (``CompileCache`` keys are
   ``(backend, scope, kind, n_pad, batch)``).
+* :func:`batch_sharding` — the ``NamedSharding`` that splits a unit's
+  batch axis over the mesh.
 * :func:`make_mesh_verdicts` — ``jit`` of the verdict pipeline with its
   input and output sharded along the mesh's batch axis.
-* :func:`make_mesh_verdict_runner` — the host-facing numpy wrapper the
-  ``sharded`` backend serves from its compile cache: pads the batch up
-  to a mesh-size multiple (empty-graph slots), runs the one sharded
-  dispatch, slices verdicts back.
+* :func:`make_mesh_fused` — ``jit`` of the fused LexBFS+PEO Pallas
+  kernel run once per shard under ``shard_map``: the per-shard program
+  the ``sharded`` backend serves at n_pad <= ``FUSED_MAX_NPAD`` on TPUs.
+* :func:`mesh_runner` — the host-facing wrapper the ``sharded`` backend
+  serves from its compile cache: pads the batch up to a mesh-size
+  multiple (empty-graph slots), ticks one dispatch, slices verdicts back.
+* :func:`make_mesh_verdict_runner` — :func:`mesh_runner` over
+  :func:`make_mesh_verdicts`.
 """
 from __future__ import annotations
 
@@ -49,7 +57,10 @@ __all__ = [
     "mesh_device_count",
     "mesh_signature",
     "pad_to_shards",
+    "batch_sharding",
     "make_mesh_verdicts",
+    "make_mesh_fused",
+    "mesh_runner",
     "make_mesh_verdict_runner",
 ]
 
@@ -111,6 +122,14 @@ def pad_to_shards(batch: int, n_shards: int) -> int:
     return -(-batch // n_shards) * n_shards
 
 
+def batch_sharding(mesh, axis_name: Optional[str] = None):
+    """``NamedSharding`` that splits axis 0 (a unit's batch) over the
+    mesh axis, whole graphs per device."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    return NamedSharding(mesh, P(axis_name or mesh.axis_names[0]))
+
+
 def make_mesh_verdicts(mesh, axis_name: Optional[str] = None) -> Callable:
     """``jit`` of the ``jax_fast`` verdict pipeline over a batch sharded
     along the mesh axis: the device-side sharded verdict program.
@@ -126,12 +145,11 @@ def make_mesh_verdicts(mesh, axis_name: Optional[str] = None) -> Callable:
     does).
     """
     import jax
-    from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.core.lexbfs import lexbfs_fast
     from repro.core.peo import peo_check
 
-    sharding = NamedSharding(mesh, P(axis_name or mesh.axis_names[0]))
+    sharding = batch_sharding(mesh, axis_name)
 
     def verdicts(adjs):
         return jax.vmap(lambda a: peo_check(a, lexbfs_fast(a)))(adjs)
@@ -140,9 +158,47 @@ def make_mesh_verdicts(mesh, axis_name: Optional[str] = None) -> Callable:
                    out_shardings=sharding)
 
 
-def make_mesh_verdict_runner(mesh) -> Callable[[np.ndarray], np.ndarray]:
-    """Host-facing executable for one ``(n_pad, batch)`` bucket: numpy
-    in, numpy out, one dispatch per call regardless of mesh size.
+def make_mesh_fused(mesh, interpret: bool) -> Callable:
+    """``jit`` of the fused LexBFS+PEO Pallas kernel once per shard:
+    ``(B, N, N)`` int8 split along the mesh axis -> ``(B,)`` verdicts
+    split the same way.
+
+    A Mosaic ``pallas_call`` is a custom call the SPMD partitioner does
+    not split, so each device gets an explicit per-shard program through
+    ``shard_map``: the single-chip ``_fused`` program on its ``B/d``
+    graphs. Nothing crosses shards, so the compiled program holds no
+    collective (asserted in ``tests/test_tpu_compile.py``).
+    ``check_vma`` is off because ``pallas_call`` declares no varying
+    axes for its outputs. The jitted function is named ``_fused`` like
+    the single-chip one, so its XLA module is ``jit__fused`` on every
+    device and the device trace finds the kernel by one name.
+    """
+    import functools
+
+    import jax
+
+    from repro.kernels.lexbfs_fused.ops import _fused as fused_shard
+
+    sharding = batch_sharding(mesh)
+    spec = sharding.spec
+
+    def shard(adjs):
+        return fused_shard(adjs, interpret=interpret)[0]
+
+    @functools.partial(jax.jit, in_shardings=(sharding,),
+                       out_shardings=sharding)
+    def _fused(adjs):
+        return jax.shard_map(shard, mesh=mesh, in_specs=spec,
+                             out_specs=spec, check_vma=False)(adjs)
+
+    return _fused
+
+
+def mesh_runner(mesh, run: Callable[[np.ndarray], np.ndarray]
+                ) -> Callable[[np.ndarray], np.ndarray]:
+    """Host-facing executable for one ``(n_pad, batch)`` bucket around
+    ``run`` (numpy in, numpy verdicts out, one sharded dispatch): one
+    dispatch per call regardless of mesh size.
 
     The planner's power-of-two batches know nothing about device counts,
     so the batch pads up to a mesh-size multiple here (all-zero
@@ -154,11 +210,10 @@ def make_mesh_verdict_runner(mesh) -> Callable[[np.ndarray], np.ndarray]:
     """
     from repro.kernels import dispatch_counter
 
-    fn = make_mesh_verdicts(mesh)
     n_shards = mesh_device_count(mesh)
     scope = mesh_signature(mesh)
 
-    def run(adjs: np.ndarray) -> np.ndarray:
+    def padded(adjs: np.ndarray) -> np.ndarray:
         b = adjs.shape[0]
         b_pad = pad_to_shards(b, n_shards)
         if b_pad != b:
@@ -167,6 +222,13 @@ def make_mesh_verdict_runner(mesh) -> Callable[[np.ndarray], np.ndarray]:
                 np.zeros((b_pad - b,) + adjs.shape[1:], dtype=adjs.dtype),
             ])
         dispatch_counter.tick(1, device=scope)
-        return np.asarray(fn(adjs))[:b]
+        return run(adjs)[:b]
 
-    return run
+    return padded
+
+
+def make_mesh_verdict_runner(mesh) -> Callable[[np.ndarray], np.ndarray]:
+    """:func:`mesh_runner` over :func:`make_mesh_verdicts`: the
+    ``sharded`` backend's ``"verdict"`` executable."""
+    fn = make_mesh_verdicts(mesh)
+    return mesh_runner(mesh, lambda adjs: np.asarray(fn(adjs)))

@@ -55,4 +55,19 @@ def test_path_check_rejects_interpreted_pallas(smoke):
 
 
 def test_sharded_phase_on_one_device(smoke):
-    smoke.sharded_phase("cpu", buckets=((16, 4), (32, 2)))
+    keys, backend = smoke.sharded_phase("cpu", buckets=((16, 4), (32, 2)))
+    assert {(k[2], k[3]) for k in keys} == {("verdict", 16), ("verdict", 32)}
+    with pytest.raises(smoke.SmokeError, match="interpret"):
+        smoke.check_sharded_paths(keys, backend)
+
+
+def test_sharded_phase_through_the_fused_program(smoke, monkeypatch):
+    """The phase as a TPU runs it, the fused kernel per shard (here
+    interpreted, on a one-device mesh), steered onto the fused kind that a
+    CPU host would not choose."""
+    from repro.engine.backends import ShardedBackend
+
+    monkeypatch.setattr(ShardedBackend, "verdict_kind",
+                        lambda self, n_pad: "fused")
+    keys, _ = smoke.sharded_phase("cpu", buckets=((16, 4), (32, 2)))
+    assert {(k[2], k[3]) for k in keys} == {("fused", 16), ("fused", 32)}

@@ -12,7 +12,10 @@ Two layers of coverage (DESIGN.md §16, TESTING.md):
   process). One child sweeps mesh sizes 1/2/4/8 and asserts bit-identity
   of verdicts vs the numpy_ref oracle, uneven unit counts (batch not a
   multiple of the mesh size), the witness fallback, and the
-  one-dispatch-per-unit invariant at every mesh size.
+  one-dispatch-per-unit invariant at every mesh size. Two more children
+  (4 and 8 devices) run the fused per-shard program (the Pallas kernel
+  under ``shard_map``, interpreted) against numpy_ref on the five graph
+  classes, and check a traced fused unit's stage spans.
 
 The emulated shards serialize on one core — these tests prove
 *partitioning correctness*, never speedups (see TESTING.md).
@@ -94,6 +97,31 @@ def test_single_device_sharded_degenerates_to_existing_path():
     assert all(k[1] == scope for k in eng.cache._fns)
 
 
+def test_sharded_verdict_kind_follows_pallas_rule():
+    """The fused per-shard kernel serves n_pad <= FUSED_MAX_NPAD only
+    where Pallas compiles (a TPU); interpreted, the jax_fast program."""
+    from repro.configs.shapes import FUSED_MAX_NPAD
+
+    backend = make_backend("sharded")
+    assert backend.interpret                    # a CPU host
+    for n_pad in (16, 64, FUSED_MAX_NPAD, 2 * FUSED_MAX_NPAD):
+        assert backend.verdict_kind(n_pad) == "verdict"
+    backend._interpret = False                  # as resolved on a TPU
+    for n_pad in (16, 64, FUSED_MAX_NPAD):
+        assert backend.verdict_kind(n_pad) == "fused"
+    assert backend.verdict_kind(2 * FUSED_MAX_NPAD) == "verdict"
+
+
+def test_single_device_fused_runner_matches_reference():
+    adjs = np.zeros((3, 16, 16), dtype=bool)
+    for i, g in enumerate((G.cycle(9), G.clique(9), G.path(16))):
+        adjs[i, :g.n_nodes, :g.n_nodes] = g.with_dense().adj
+    c0 = dispatch_counter.count
+    out = make_backend("sharded").compile_fused_batch(16, 3)(adjs)
+    assert dispatch_counter.count - c0 == 1
+    np.testing.assert_array_equal(out, [False, True, True])
+
+
 def test_mesh_runner_pads_uneven_batches():
     run = make_mesh_verdict_runner(build_mesh(1))
     adjs = np.stack([g.with_dense().adj for g in
@@ -168,7 +196,7 @@ print("ALL-OK")
 """
 
 
-def _run_emulated(script: str, n_devices: int = 8) -> str:
+def _run_emulated(script: str, n_devices: int = 8, args=()) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = (
         env.get("XLA_FLAGS", "")
@@ -177,8 +205,8 @@ def _run_emulated(script: str, n_devices: int = 8) -> str:
     env["PYTHONPATH"] = str(ROOT / "src") + os.pathsep + \
         env.get("PYTHONPATH", "")
     p = subprocess.run(
-        [sys.executable, "-c", script], capture_output=True, text=True,
-        env=env, cwd=str(ROOT), timeout=600)
+        [sys.executable, "-c", script, *args], capture_output=True,
+        text=True, env=env, cwd=str(ROOT), timeout=600)
     assert p.returncode == 0, (
         f"child failed ({p.returncode})\n"
         f"stdout:\n{p.stdout}\nstderr:\n{p.stderr[-4000:]}")
@@ -190,3 +218,90 @@ def test_sharded_bit_identity_across_emulated_mesh_sizes():
     for d in (1, 2, 4, 8):
         assert f"MESH-OK d={d}" in out
     assert "ALL-OK" in out
+
+
+# ---------------------------------------------------------------------------
+# The fused per-shard program (kind "fused": the Pallas kernel under
+# shard_map), interpreted on emulated meshes of 4 and 8 devices.
+# ---------------------------------------------------------------------------
+_FUSED_CHILD = r"""
+import sys
+
+import numpy as np
+import jax
+
+from repro import obs
+from repro.core import generators as G
+from repro.engine.backends import make_backend
+from repro.engine.session import ChordalityEngine
+from repro.kernels import dispatch_counter
+
+d = int(sys.argv[1])
+assert jax.device_count() == d, f"emulation failed: {jax.device_count()}"
+backend = make_backend("sharded")
+assert backend.interpret and backend.cache_scope() == f"cpu:mesh{d}"
+reference = make_backend("numpy_ref").compile_batch(0, 0)
+# The five graph classes of the thesis's section 7.
+MAKERS = (lambda n, s: G.clique(n),
+          lambda n, s: G.dense_random(n, 0.5, seed=s),
+          lambda n, s: G.sparse_random(n, avg_degree=4, seed=s),
+          lambda n, s: G.random_tree(n, seed=s),
+          lambda n, s: G.k_tree(n, k=3, seed=s))
+
+
+def unit(n_pad, b, seed):
+    rng = np.random.default_rng(seed)
+    adjs = np.zeros((b, n_pad, n_pad), dtype=bool)
+    for i in range(b):
+        n = int(rng.integers(n_pad // 2 + 1, n_pad + 1))
+        adjs[i, :n, :n] = MAKERS[i % 5](n, seed + i).with_dense().adj[:n, :n]
+    return adjs
+
+
+# Batch 6 pads to 8: on 4 devices the last shard holds only padding.
+for n_pad in (64, 128):
+    for b in (8, 4, 6):
+        adjs = unit(n_pad, b, 100 * n_pad + b)
+        want = reference(adjs)
+        assert want.any() and not want.all()
+        c0 = dispatch_counter.count
+        got = backend.compile_fused_batch(n_pad, b)(adjs)
+        assert dispatch_counter.count - c0 == 1, f"n_pad={n_pad} b={b}"
+        np.testing.assert_array_equal(
+            got, want, err_msg=f"d={d} n_pad={n_pad} b={b}")
+print(f"FUSED-OK d={d}")
+
+# A traced unit through the engine: the test steers the backend onto its
+# fused kind, which a CPU mesh would not choose (Pallas is interpreted).
+backend.verdict_kind = lambda n_pad: "fused"
+graphs = [MAKERS[i % 5](40 + i, i) for i in range(6)]
+eng = ChordalityEngine(backend=backend, max_batch=8)
+sink = obs.ListSink()
+obs.enable_tracing(sink)
+try:
+    c0 = dispatch_counter.count
+    res = eng.run(graphs)
+    ticks = dispatch_counter.count - c0
+finally:
+    obs.disable_tracing()
+np.testing.assert_array_equal(
+    res.verdicts,
+    ChordalityEngine(backend="numpy_ref").run(graphs).verdicts)
+units = [s for root in sink.spans for s in root.walk() if s.name == "unit"]
+assert len(units) == len(res.plan.units) == ticks == 1, (len(units), ticks)
+(u,) = units
+assert u.attrs["kind"] == "fused" and u.attrs["batch"] == 8
+dispatch = u.find("dispatch")
+assert [c.name for c in dispatch.children] == \
+    ["pack", "h2d", "device", "d2h"], dispatch.children
+h2d = dispatch.children[1]
+assert h2d.attrs == {"bytes": 8 * 64 * 64, "devices": d}, h2d.attrs
+print(f"TRACED-OK d={d}")
+"""
+
+
+@pytest.mark.parametrize("n_devices", [4, 8])
+def test_sharded_fused_kernel_matches_reference_on_emulated_mesh(n_devices):
+    out = _run_emulated(_FUSED_CHILD, n_devices, args=(str(n_devices),))
+    assert f"FUSED-OK d={n_devices}" in out
+    assert f"TRACED-OK d={n_devices}" in out

@@ -5,8 +5,9 @@ Interpret mode cannot see what Mosaic refuses (block shapes off the
 over the scoped limit). These tests compile each kernel with
 ``interpret=False`` for a described ``v5e:2x2`` topology — no chip is
 attached, nothing runs — at the engine buckets the ``pallas_peo`` path
-serves, and the ``sharded`` backend's program over the four described
-chips, which must hold no collective.
+serves, and the ``sharded`` backend's programs over the four described
+chips (the ``jax_fast`` one and the fused kernel per shard), which must
+hold no collective.
 
 The topology is described inside a module fixture (libtpu is loaded only
 by the worker that runs this file) and the persistent compile cache is off
@@ -91,16 +92,43 @@ def test_peo_check_kernels_compile_at_largest_bucket(one_chip, kernel):
             a, ap, pos, p, interpret=False), mat, mat, vec, vec)
 
 
+COLLECTIVES = ("all-gather", "all-reduce", "collective-permute",
+               "all-to-all", "reduce-scatter")
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    from jax.sharding import Mesh
+
+    from repro.engine.mesh import MESH_AXIS
+
+    return Mesh(np.asarray(topo.devices), (MESH_AXIS,))
+
+
 @pytest.mark.parametrize("n_pad,batch", [(256, 32), (2048, 8)])
-def test_sharded_verdicts_compile_without_collectives(topo, n_pad, batch):
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+def test_sharded_verdicts_compile_without_collectives(mesh4, n_pad, batch):
+    from repro.engine.mesh import batch_sharding, make_mesh_verdicts
 
-    from repro.engine.mesh import MESH_AXIS, make_mesh_verdicts
-
-    mesh = Mesh(np.asarray(topo.devices), (MESH_AXIS,))
     x = jax.ShapeDtypeStruct((batch, n_pad, n_pad), jnp.bool_,
-                             sharding=NamedSharding(mesh, P(MESH_AXIS)))
-    hlo = make_mesh_verdicts(mesh).lower(x).compile().as_text()
-    for op in ("all-gather", "all-reduce", "collective-permute",
-               "all-to-all", "reduce-scatter"):
+                             sharding=batch_sharding(mesh4))
+    hlo = make_mesh_verdicts(mesh4).lower(x).compile().as_text()
+    for op in COLLECTIVES:
         assert op not in hlo, f"sharded program holds {op}"
+
+
+@pytest.mark.parametrize("n_pad,batch", [(2048, 128), (2048, 4)])
+def test_sharded_fused_compiles_per_shard_without_collectives(
+        mesh4, n_pad, batch):
+    """The fused kernel once per chip: a Mosaic kernel in the program,
+    no collective, and one 32-graph (or 1-graph) shard per chip."""
+    from repro.engine.mesh import batch_sharding, make_mesh_fused
+
+    x = jax.ShapeDtypeStruct((batch, n_pad, n_pad), jnp.int8,
+                             sharding=batch_sharding(mesh4))
+    compiled = make_mesh_fused(mesh4, interpret=False).lower(x).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    for op in COLLECTIVES:
+        assert op not in hlo, f"sharded fused program holds {op}"
+    per_chip = batch // 4 * n_pad * n_pad
+    assert compiled.memory_analysis().argument_size_in_bytes == per_chip

@@ -53,6 +53,7 @@ from repro.engine.mesh import (
 from repro.engine.planner import (
     CompileCache,
     Plan,
+    RealizePool,
     WorkUnit,
     plan_requests,
     realize_unit,
@@ -106,6 +107,7 @@ __all__ = [
     "pad_to_shards",
     "CompileCache",
     "Plan",
+    "RealizePool",
     "WorkUnit",
     "plan_requests",
     "realize_unit",

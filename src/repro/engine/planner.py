@@ -20,8 +20,10 @@ was compiled against.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+import threading
+from typing import Callable, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -38,6 +40,15 @@ _M_CACHE_HITS = obs.registry.counter(
 _M_CACHE_MISSES = obs.registry.counter(
     "repro_compile_cache_misses_total",
     "compile-cache misses (each builds an executable)")
+# Dense units realized into a pooled host buffer (RealizePool): a reuse
+# fills a buffer kept from an earlier unit, an allocation makes a new one.
+# Their ratio is the pool's hit share.
+_M_REALIZE_REUSES = obs.registry.counter(
+    "repro_realize_buffer_reuses_total",
+    "dense work units realized into a host buffer kept from an earlier unit")
+_M_REALIZE_ALLOCS = obs.registry.counter(
+    "repro_realize_buffer_allocs_total",
+    "host unit buffers allocated by the realize pool")
 # XLA compiles themselves happen at an executable's first call, not when
 # the cache builds it: they are counted by repro.obs.compiles.
 obs.install_compile_listener()
@@ -152,6 +163,123 @@ def realize_unit(
         n = g.n_nodes
         out[slot, :n, :n] = g.adj[:n, :n]
     return out
+
+
+class _UnitBuffer:
+    """One pooled host buffer: a C-contiguous ``(capacity, n_pad, n_pad)``
+    bool array, plus per slot the extent ``p`` such that everything
+    outside ``[:p, :p]`` of that slot is zero."""
+
+    __slots__ = ("array", "extents")
+
+    def __init__(self, n_pad: int, capacity: int):
+        self.array = np.zeros((capacity, n_pad, n_pad), dtype=bool)
+        self.extents = [0] * capacity
+
+    @property
+    def capacity(self) -> int:
+        return self.array.shape[0]
+
+    def fill(self, unit: WorkUnit, graphs: Sequence[Graph]) -> np.ndarray:
+        """Realize ``unit`` into the leading ``unit.batch`` slots; returns
+        that view, equal to :func:`realize_unit`'s array. Each slot copies
+        its graph's ``[:n, :n]`` block and zeroes only what the slot's last
+        graph left outside it; padding slots are zeroed over their old
+        extent."""
+        arr, extents = self.array, self.extents
+        for slot in range(unit.batch):
+            n = 0
+            if slot < len(unit.indices):
+                g = graphs[unit.indices[slot]].with_dense()
+                n = g.n_nodes
+                arr[slot, :n, :n] = g.adj[:n, :n]
+            p = extents[slot]
+            if p > n:
+                arr[slot, :n, n:p] = False
+                arr[slot, n:p, :p] = False
+            extents[slot] = n
+        return arr[: unit.batch]
+
+
+class RealizePool:
+    """Host buffers for dense work units, kept from one unit to the next.
+
+    A fresh ``(batch, n_pad, n_pad)`` array per unit (128 MB at B=32,
+    n_pad=2048) costs a page fault and a kernel zero per page before the
+    copy even starts; a kept buffer is only rewritten where it must be
+    (:meth:`_UnitBuffer.fill`). Buffers are keyed by ``n_pad``; each has
+    the capacity of the largest batch asked for at its ``n_pad``, and a
+    unit uses the leading ``[:batch]`` view, which is contiguous, so the
+    fused dispatch still hands the device the buffer's own bytes.
+
+    :meth:`realized` checks a buffer out and returns it when its block
+    ends: callers keep the block open until the unit's executable has
+    returned host verdicts, by which time the device has read the input.
+    Checkout and return hold a lock, so executor lanes running at once
+    hold different buffers, and a new buffer is made only when every
+    buffer at that ``n_pad`` is out or too small: the pool never holds
+    more buffers per ``n_pad`` than units were ever in flight at once
+    there. A block that raises (in realize or in the executable) drops
+    its buffer instead of trusting its extents.
+    """
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._idle: Dict[int, List[_UnitBuffer]] = {}
+        self._capacity: Dict[int, int] = {}
+        self._leased: Dict[int, _UnitBuffer] = {}
+        self.reuses = 0
+        self.allocs = 0
+
+    def __len__(self) -> int:
+        """Buffers held, idle and checked out."""
+        with self._lock:
+            return (sum(len(v) for v in self._idle.values())
+                    + len(self._leased))
+
+    def leased(self) -> List[np.ndarray]:
+        """The arrays of the buffers checked out now."""
+        with self._lock:
+            return [b.array for b in self._leased.values()]
+
+    def _checkout(self, n_pad: int, batch: int) -> _UnitBuffer:
+        with self._lock:
+            cap = max(batch, self._capacity.get(n_pad, 0))
+            self._capacity[n_pad] = cap
+            idle = self._idle.get(n_pad)
+            buf = idle.pop() if idle else None
+            if buf is not None and buf.capacity >= batch:
+                self.reuses += 1
+                _M_REALIZE_REUSES.inc()
+            else:
+                buf = _UnitBuffer(n_pad, cap)
+                self.allocs += 1
+                _M_REALIZE_ALLOCS.inc()
+            if id(buf) in self._leased:
+                raise RuntimeError("realize buffer checked out twice")
+            self._leased[id(buf)] = buf
+            return buf
+
+    def _return(self, buf: _UnitBuffer, keep: bool) -> None:
+        with self._lock:
+            if self._leased.pop(id(buf), None) is None:
+                raise RuntimeError("realize buffer returned twice")
+            if keep:
+                self._idle.setdefault(buf.array.shape[1], []).append(buf)
+
+    @contextlib.contextmanager
+    def realized(self, unit: WorkUnit,
+                 graphs: Sequence[Graph]) -> Iterator[np.ndarray]:
+        """Check out a buffer, realize ``unit`` into it and yield the
+        ``(batch, n_pad, n_pad)`` view; the buffer goes back to the pool
+        when the block exits cleanly and is dropped when it raises."""
+        buf = self._checkout(unit.n_pad, unit.batch)
+        try:
+            yield buf.fill(unit, graphs)
+        except BaseException:
+            self._return(buf, keep=False)
+            raise
+        self._return(buf, keep=True)
 
 
 def realize_unit_csr(unit: WorkUnit, graphs: Sequence[Graph]):

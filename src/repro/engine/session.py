@@ -31,6 +31,7 @@ host-array contract.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Dict, List, Optional, Sequence, Union
 
@@ -45,6 +46,7 @@ from repro.engine.backends import (
 from repro.engine.planner import (
     CompileCache,
     Plan,
+    RealizePool,
     plan_requests,
     realize_unit,
     realize_unit_csr,
@@ -171,6 +173,9 @@ class ChordalityEngine:
         self.buckets = tuple(buckets) if buckets is not None else None
         self.witness_default = witness
         self.cache = CompileCache()
+        # Host buffers the verdict path realizes dense units into, kept
+        # from one unit to the next (planner.RealizePool).
+        self.realize_pool = RealizePool()
         # Engine-lifetime measurement log feeding refit_router(); every
         # execute_unit appends one (backend, n, density, batch,
         # device_count, us/graph) row, from sync runs and the async
@@ -373,10 +378,18 @@ class ChordalityEngine:
         appends to the shared log)."""
         backend = self._resolve(unit.backend)
         kind = backend.verdict_kind(unit.n_pad)
+        # A dense unit lives in a pooled buffer, returned when the stack
+        # closes: after the executable has handed back host verdicts, so
+        # the device has read its input (DESIGN.md §6).
         with obs.span("unit", n_pad=unit.n_pad, batch=unit.batch,
-                      backend=backend.name, kind=kind):
+                      backend=backend.name, kind=kind), \
+                contextlib.ExitStack() as lease:
             with obs.span("realize"):
-                payload = self._realize(backend, unit, graphs)
+                if backend.caps.sparse:
+                    payload = realize_unit_csr(unit, graphs)
+                else:
+                    payload = lease.enter_context(
+                        self.realize_pool.realized(unit, graphs))
             fn = self.cache.get(backend, unit.n_pad, unit.batch, kind=kind)
             t1 = _clock.now()
             with obs.span("dispatch", backend=backend.name, kind=kind):

@@ -86,13 +86,15 @@ def test_batch_padding_slots_do_not_leak(backend):
 
 
 # ---------------------------------------------------------------------------
-# pallas_peo's fused runners: a realized bool unit goes to the device as an
-# int8 view of its own bytes; anything else is converted once and counted.
+# pallas_peo's fused runners: a realized bool unit, fresh or in the realize
+# pool's buffer, goes to the device as an int8 view of its own bytes;
+# anything else is converted once and counted.
 # ---------------------------------------------------------------------------
 _PACK_N = {"fused": 70, "fused_packed": 9, "fused_witness": 9}
 
 
-@pytest.mark.parametrize("case", ["bool", "int64", "list", "noncanonical"])
+@pytest.mark.parametrize("case", ["bool", "int64", "list", "noncanonical",
+                                  "pooled"])
 @pytest.mark.parametrize("kind", sorted(_PACK_N))
 def test_fused_pack_views_bool_units(monkeypatch, kind, case):
     import jax.numpy as jnp
@@ -100,13 +102,16 @@ def test_fused_pack_views_bool_units(monkeypatch, kind, case):
     from repro import obs
     from repro.core.lexbfs import lexbfs_numpy_dense
     from repro.core.peo import peo_check_numpy
-    from repro.engine.backends import PallasPeoBackend
-    from repro.engine.planner import plan_requests, realize_unit
+    from repro.engine.backends import PallasPeoBackend, _pack
+    from repro.engine.planner import RealizePool, plan_requests, realize_unit
 
     n = _PACK_N[kind]
     graphs = [G.cycle(n), G.random_chordal(n, k=3, seed=0)]
     (unit,) = plan_requests(graphs, max_batch=2).units
     realized = realize_unit(unit, graphs)
+    pool = RealizePool()
+    with pool.realized(unit, graphs) as pooled:
+        (pool_buf,) = pool.leased()
     backend = PallasPeoBackend(interpret=True, pipeline="fused")
     assert kind == (backend.witness_kind(unit.n_pad) if kind == "fused_witness"
                     else backend.verdict_kind(unit.n_pad))
@@ -114,7 +119,8 @@ def test_fused_pack_views_bool_units(monkeypatch, kind, case):
     payload = {"bool": realized,
                "int64": realized.astype(np.int64),
                "list": realized.tolist(),
-               "noncanonical": (realized.view(np.uint8) * 2).view(bool)}[case]
+               "noncanonical": (realized.view(np.uint8) * 2).view(bool),
+               "pooled": pooled}[case]
 
     sent = []
     real_asarray = jnp.asarray
@@ -134,6 +140,10 @@ def test_fused_pack_views_bool_units(monkeypatch, kind, case):
     assert h2d.dtype == np.int8
     if case == "bool":
         assert np.shares_memory(h2d, realized)
+    if case == "pooled":
+        np.testing.assert_array_equal(pooled, realized)
+        assert np.shares_memory(h2d, pool_buf)
+        assert all(np.shares_memory(a, pool_buf) for a in _pack(pooled))
     if case == "int64":
         assert not np.shares_memory(h2d, payload)
     want = [peo_check_numpy(a, lexbfs_numpy_dense(a)) for a in realized]

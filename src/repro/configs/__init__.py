@@ -1,63 +1,14 @@
-"""Architecture registry: ``--arch <id>`` resolves here."""
-from repro.configs import (
-    arctic_480b,
-    chordality,
-    dcn_v2,
-    egnn,
-    gcn_cora,
-    glm4_9b,
-    graphsage_reddit,
-    h2o_danube_1_8b,
-    llama4_maverick_400b_a17b,
-    pna,
-    qwen1_5_4b,
-)
-from repro.configs.base import ArchSpec
+"""The chordality service's configuration: ``ServiceConfig`` and its presets."""
 from repro.configs.service import (
     SERVICE_CONFIGS,
     AutotuneConfig,
     ServiceConfig,
     service_config,
 )
-from repro.configs.shapes import (
-    CHORDALITY_SHAPES,
-    GNN_SHAPES,
-    LM_SHAPES,
-    RECSYS_SHAPES,
-    shapes_for_family,
-)
 
-ARCHS = {
-    spec.arch_id: spec
-    for spec in [
-        h2o_danube_1_8b.SPEC,
-        glm4_9b.SPEC,
-        qwen1_5_4b.SPEC,
-        arctic_480b.SPEC,
-        llama4_maverick_400b_a17b.SPEC,
-        gcn_cora.SPEC,
-        egnn.SPEC,
-        graphsage_reddit.SPEC,
-        pna.SPEC,
-        dcn_v2.SPEC,
-        chordality.SPEC,
-    ]
-}
-
-
-def get_arch(arch_id: str) -> ArchSpec:
-    if arch_id not in ARCHS:
-        raise KeyError(
-            f"unknown arch {arch_id!r}; available: {sorted(ARCHS)}")
-    return ARCHS[arch_id]
-
-
-def all_cells():
-    """Every (arch_id, shape_id, skip_reason|None) cell in the assignment."""
-    cells = []
-    for arch_id, spec in ARCHS.items():
-        if arch_id == "chordality":
-            continue  # the paper's own config is extra, not an assigned cell
-        for shape_id in shapes_for_family(spec.family):
-            cells.append((arch_id, shape_id, spec.skipped(shape_id)))
-    return cells
+__all__ = [
+    "SERVICE_CONFIGS",
+    "AutotuneConfig",
+    "ServiceConfig",
+    "service_config",
+]

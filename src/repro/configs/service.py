@@ -5,10 +5,7 @@ latency for batch occupancy with two knobs: how long the admission loop may
 hold a partially-filled bucket (``max_wait_ms``) and how many requests fill
 a bucket (``max_batch``).  ``max_queue`` bounds the total backlog a service
 will accept — admission control, the knob that keeps queue delay finite
-under overload.  Named presets capture the standard operating points; the
-service benchmark (``benchmarks.run --tables service``) sweeps
-``max_wait_ms`` to expose the tradeoff curve, and the saturation benchmark
-(``--tables saturation``) sweeps offered load to the knee.
+under overload.  Named presets capture the standard operating points.
 
 :class:`AutotuneConfig` closes the control loops the static knobs leave
 open (``repro.engine.autotune``): an AIMD controller adapts the wait

@@ -1,100 +1,10 @@
-"""Input-shape registry — the per-family shape sets from the assignment.
-
-Every (arch × shape) pair is one dry-run cell; the launcher resolves
-(family, shape_id) here.
+"""Shape planning for the chordality engine: the padding buckets every
+work unit is compiled against, and the VMEM and host-memory models that
+cap which kernel serves a bucket.
 """
 from __future__ import annotations
 
-import dataclasses
 from typing import Optional, Tuple
-
-
-@dataclasses.dataclass(frozen=True)
-class LMShape:
-    shape_id: str
-    seq_len: int
-    global_batch: int
-    mode: str                      # train | prefill | decode
-
-
-LM_SHAPES = {
-    "train_4k": LMShape("train_4k", 4_096, 256, "train"),
-    "prefill_32k": LMShape("prefill_32k", 32_768, 32, "prefill"),
-    "decode_32k": LMShape("decode_32k", 32_768, 128, "decode"),
-    "long_500k": LMShape("long_500k", 524_288, 1, "decode"),
-}
-
-
-@dataclasses.dataclass(frozen=True)
-class GNNShape:
-    shape_id: str
-    n_nodes: int
-    n_edges: int                   # directed edge entries
-    d_feat: int
-    n_classes: int
-    mode: str                      # full | sampled | batched
-    batch_graphs: int = 1
-    batch_nodes: int = 0           # sampled-mode seeds
-    fanout: Tuple[int, ...] = ()
-
-
-GNN_SHAPES = {
-    "full_graph_sm": GNNShape(
-        "full_graph_sm", 2_708, 10_556, 1_433, 7, "full"),
-    "minibatch_lg": GNNShape(
-        "minibatch_lg", 232_965, 114_615_892, 602, 41, "sampled",
-        batch_nodes=1_024, fanout=(15, 10)),
-    "ogb_products": GNNShape(
-        "ogb_products", 2_449_029, 61_859_140, 100, 47, "full"),
-    "molecule": GNNShape(
-        "molecule", 30, 64, 16, 2, "batched", batch_graphs=128),
-}
-
-
-def sampled_pad_sizes(shape: GNNShape) -> Tuple[int, int]:
-    """Worst-case padded (nodes, edges) for the sampled-training cell."""
-    n_pad = shape.batch_nodes
-    e_pad = 0
-    frontier = shape.batch_nodes
-    for f in shape.fanout:
-        e_pad += frontier * f
-        frontier *= f
-        n_pad += frontier
-    return n_pad, e_pad
-
-
-@dataclasses.dataclass(frozen=True)
-class RecsysShape:
-    shape_id: str
-    batch: int
-    mode: str                      # train | serve | retrieval
-    n_candidates: int = 0
-
-
-RECSYS_SHAPES = {
-    "train_batch": RecsysShape("train_batch", 65_536, "train"),
-    "serve_p99": RecsysShape("serve_p99", 512, "serve"),
-    "serve_bulk": RecsysShape("serve_bulk", 262_144, "serve"),
-    "retrieval_cand": RecsysShape(
-        "retrieval_cand", 1, "retrieval", n_candidates=1_000_000),
-}
-
-
-@dataclasses.dataclass(frozen=True)
-class ChordalityShape:
-    """The paper's own workload: a batch of N-vertex graphs."""
-    shape_id: str
-    n_vertices: int
-    batch: int
-    graph_class: str               # paper §7 classes
-
-
-CHORDALITY_SHAPES = {
-    "cliques_10k": ChordalityShape("cliques_10k", 10_240, 32, "cliques"),
-    "dense_10k": ChordalityShape("dense_10k", 10_240, 32, "dense"),
-    "sparse_10k": ChordalityShape("sparse_10k", 10_240, 32, "sparse"),
-    "chordal_10k": ChordalityShape("chordal_10k", 10_240, 32, "chordal"),
-}
 
 
 # ---------------------------------------------------------------------------
@@ -296,11 +206,3 @@ def engine_deg_bucket(deg: int, n_pad: int) -> int:
         b <<= 1
     return min(b, max(n_pad, 1))
 
-
-def shapes_for_family(family: str):
-    return {
-        "lm": LM_SHAPES,
-        "gnn": GNN_SHAPES,
-        "recsys": RECSYS_SHAPES,
-        "chordality": CHORDALITY_SHAPES,
-    }[family]

@@ -46,7 +46,7 @@ other in tests):
 Work: O(N) per cheap iteration, O(N²·N/K) comparator total (K ≈ 30−log₂N
 cheap steps per compaction) — the extra factor buys scatter-free,
 lane-parallel inner loops that measure faster on both CPU and VPU at the
-engine's bucket sizes (BENCH_kernels.json records the factors). Depth per
+engine's bucket sizes. Depth per
 iteration is O(log N) (the argmax/compare reductions), vs the paper's O(1)
 PRAM claim; total O(N log N) depth (honest delta, DESIGN.md §7/§11).
 """
@@ -119,9 +119,7 @@ def lexbfs_scan(adj: jnp.ndarray, return_pos: bool = False):
 def lexbfs_batched_scan(adj_batch: jnp.ndarray) -> jnp.ndarray:
     """vmap-of-scan over (B, N, N) — the pre-restructure batched form.
 
-    Kept as the benchmark baseline (``BENCH_kernels.json`` records the
-    batch-major path's speedup against it) and as a second differential
-    reference in tests.
+    Kept as a second differential reference in tests.
     """
     return jax.vmap(lambda a: lexbfs_scan(a))(adj_batch)
 

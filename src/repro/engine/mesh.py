@@ -205,8 +205,8 @@ def mesh_runner(mesh, run: Callable[[np.ndarray], np.ndarray]
     adjacency slots — their verdicts are computed and discarded) and the
     verdict vector slices back to the caller's ``b``. The dispatch
     counter ticks once per call under the mesh's device scope, which is
-    what ``BENCH_mesh.json`` reads to prove sharding never multiplies
-    host launches.
+    what ``tests/test_mesh.py`` and ``tests/test_exact_counts.py`` read
+    to prove sharding never multiplies host launches.
     """
     from repro.kernels import dispatch_counter
 

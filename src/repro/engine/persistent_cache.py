@@ -2,7 +2,7 @@
 
 Compiled executables (XLA programs and Mosaic kernels) are written to disk
 so a second process on the same machine skips the compiles. Entry points
-(``chip_smoke.py``, ``examples/serve_chordality.py``, ``benchmarks/run.py``)
+(``chip_smoke.py``, ``examples/serve_chordality.py``, ``bench/run.py``)
 call :func:`enable_persistent_cache` before their first compile; library
 imports and the tests never do.
 """

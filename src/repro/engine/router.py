@@ -22,12 +22,15 @@ recovered exactly. ``D`` is clamped to the router's *fitted* device
 support (``fit_device_range``) — a model fitted from single-device live
 logs must never extrapolate multi-device costs (clamp_features).
 
-``DEFAULT_COST_MODEL`` is least-squares fitted from
-``benchmarks.kernel_bench.bench_router_samples`` measurements on the
-2-core CPU CI reference box (see DESIGN.md §8 for the measured crossovers);
-:func:`fit_cost_model` re-fits from fresh samples so other hosts can
-calibrate. Routing only needs the *ordering* of backends per regime, which
-is robust to modest coefficient error:
+``DEFAULT_COST_MODEL`` and its witness / recognition siblings are
+constants least-squares fitted once from offline samples on a 2-core CPU
+reference host; the sampler that produced them is no longer in the repo,
+so the tables are not regenerated offline. They are re-fitted online:
+``ChordalityEngine.refit_router`` runs :func:`fit_cost_model` over the
+engine's live unit samples, and the service's
+:class:`~repro.engine.autotune.RefitPolicy` decides when. Routing only
+needs the *ordering* of backends per regime, which is robust to modest
+coefficient error:
 
 * tiny graphs → ``numpy_ref`` (no dispatch, no compile);
 * sparse, large n → ``csr`` (O(N+M) operands, batch-amortized sweeps);
@@ -73,15 +76,13 @@ class BackendCost:
 
 CostModel = Mapping[str, BackendCost]
 
-# Fitted on the CI reference host from bench_router_samples (warm
-# engines, best-of-5 sub-ms cells); re-fitted in PR 6 — in the *same
-# session* as DEFAULT_WITNESS_COST_MODEL, so cross-mode comparisons
-# (estimate_us_per_graph mode="witness" vs verdict) are coherent — after
-# the numpy-in/numpy-out wrapper restructure cut jax_fast's per-unit
-# dispatch cost ~4x. Regenerate via
-#   PYTHONPATH=src python -m benchmarks.run --tables router
-# and repro.engine.router.fit_cost_model (or online:
-# ChordalityEngine.refit_router). Measured crossovers this model encodes:
+# CPU-fitted constants (2-core reference host, warm engines, best-of-5
+# sub-ms cells); fitted in the *same session* as
+# DEFAULT_WITNESS_COST_MODEL, so cross-mode comparisons
+# (estimate_us_per_graph mode="witness" vs verdict) are coherent. The
+# offline sampler that produced them is gone; re-fit online through
+# ChordalityEngine.refit_router (fit_cost_model over live unit samples)
+# or the service's RefitPolicy. Measured crossovers this model encodes:
 # jax_fast wins tiny through dense-bulk traffic (the PR 6 wrapper fix
 # dropped its dispatch floor below numpy_ref's per-graph python cost, so
 # numpy_ref no longer wins single-shot tiny requests — it remains the
@@ -105,8 +106,8 @@ DEFAULT_COST_MODEL: Dict[str, BackendCost] = {
     # reference host in *interpret* mode — the only Pallas substrate a CPU
     # box has — where the emulation compiles to roughly the jnp path's
     # speed; it stays out of CPU auto-routing because it is not in
-    # DEFAULT_CANDIDATES. A TPU deployment re-fits via --tables router (or
-    # ChordalityEngine.refit_router) and opts it into the candidate list.
+    # DEFAULT_CANDIDATES. A TPU deployment re-fits online via
+    # ChordalityEngine.refit_router and opts it into the candidate list.
     "pallas_peo": BackendCost(
         dispatch_us=847.6, per_graph_us=0.0, sweep_us=0.0,
         n_us=0.3358, n2_us=0.009781, m_us=0.0),
@@ -119,9 +120,9 @@ DEFAULT_COST_MODEL: Dict[str, BackendCost] = {
 # clique loops, jax_fast pays one heavier fused batch-major program, csr
 # pays segment-reduction passes over edge windows), so routing certified
 # traffic off the verdict coefficients would misplace every crossover.
-# Fitted on the CI reference host (PR 6) in the same session as
-# DEFAULT_COST_MODEL, over the bench_router_samples grid measured with
-# witness=True; re-fit via fit_cost_model over
+# Fitted on the CPU reference host in the same session as
+# DEFAULT_COST_MODEL, over an offline sample grid measured with
+# witness=True; re-fit online via fit_cost_model over live
 # (backend, n, density, batch, us) rows.
 DEFAULT_WITNESS_COST_MODEL: Dict[str, BackendCost] = {
     "numpy_ref": BackendCost(
@@ -146,8 +147,8 @@ DEFAULT_WITNESS_COST_MODEL: Dict[str, BackendCost] = {
 # verdict runs 1, the LexBFS+ selection does two reductions per step, and
 # numpy_ref pays python-loop sweeps per graph while jax_fast amortizes one
 # bigger jit program per unit — so recognition crossovers sit elsewhere
-# than verdict ones. Fitted (PR 7) on the CI reference host via
-# fit_cost_model over the bench_router_samples grid (n 8–512, B 1–16)
+# than verdict ones. Fitted on the CPU reference host via
+# fit_cost_model over an offline sample grid (n 8–512, B 1–16)
 # measured with properties=<full 5-property registry> — the conservative
 # plan: pricing lighter property sets with it only overestimates both
 # candidates the same way, preserving ordering. Only the
@@ -170,17 +171,17 @@ DEFAULT_CANDIDATES: Tuple[str, ...] = ("numpy_ref", "jax_fast", "csr")
 # CPU CI reference fit; a platform overlay replaces/extends entries whose
 # measured coefficients differ structurally — today that is the sharded
 # mesh backend, whose device_count terms only exist where a mesh was
-# actually measured. The CPU entry is fitted from BENCH_mesh.json's
-# 8-device *emulated* scaling run (serialized shards — see TESTING.md),
-# so on CPU it prices sharding as batch-partitioning overhead, which is
-# honest there; a TPU/GPU deployment re-fits via refit_router() or
-# --tables router on real hardware and gets real dev_us coefficients.
+# actually measured. The CPU entry was fitted from an 8-device
+# *emulated* scaling run (serialized shards — see TESTING.md), so on CPU
+# it prices sharding as batch-partitioning overhead, which is honest
+# there; a TPU/GPU deployment re-fits via refit_router() on real
+# hardware and gets real dev_us coefficients.
 # Opt in via Router(platform="cpu", candidates=(*DEFAULT_CANDIDATES,
 # "sharded"), fit_device_range=(1, 8)).
 PLATFORM_COST_MODELS: Dict[str, Dict[str, BackendCost]] = {
     "cpu": {
-        # Fitted via fit_cost_model over live unit samples from the
-        # BENCH_mesh calibration grid (n 64/128/256, B 32, D 1/2/4/8
+        # Fitted via fit_cost_model over live unit samples from a
+        # mesh calibration grid (n 64/128/256, B 32, D 1/2/4/8
         # emulated devices, 72 samples): a fixed per-graph cost plus an
         # n²/D compute term and a per-device partition/reassembly cost.
         "sharded": BackendCost(
@@ -410,9 +411,8 @@ def fit_cost_model(
 
     ``samples`` rows are ``(backend, n, density, batch, us_per_graph)``
     or, since PR 10, ``(backend, n, density, batch, device_count,
-    us_per_graph)`` — the formats
-    ``benchmarks.kernel_bench.bench_router_samples`` and the engine's
-    live unit-sample log emit. 5-field rows fit at ``device_count=1``.
+    us_per_graph)`` — the format the engine's live unit-sample log
+    emits. 5-field rows fit at ``device_count=1``.
     The fit is *relative* (rows weighted by 1/µs — routing needs tiny-n
     rows as accurate as big-n rows), masked per backend
     (:data:`FIT_FEATURE_MASKS`), and clipped at 0 (a negative term has no

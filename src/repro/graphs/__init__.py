@@ -1,4 +1,4 @@
-"""Graph substrate: structures, generators, sampling, preprocessing."""
+"""Graph structures: dense and CSR constructors, padding and bucketing."""
 from repro.graphs.structure import (
     Graph,
     dense_from_edges,

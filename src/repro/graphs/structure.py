@@ -5,9 +5,9 @@ Two representations are used throughout the framework:
 * **Dense adjacency matrix** ``(N, N) bool`` — the paper's native format
   (its CUDA implementation stores ``Adj`` as an N x N boolean array and every
   thread owns one row). The chordality core operates on this.
-* **Edge index** ``(2, E) int32`` + CSR (``indptr``/``indices``) — the GNN
-  substrate format; message passing uses ``jax.ops.segment_sum`` over the
-  edge index, and the neighbor sampler walks CSR.
+* **Edge index** ``(2, E) int32`` + CSR (``indptr``/``indices``) — the
+  sparse format; the CSR backend (``repro.sparse``) packs work units
+  from it.
 
 All constructors are host-side (numpy) because graph construction is a data
 pipeline step; device code receives ``jnp`` arrays.

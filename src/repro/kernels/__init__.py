@@ -1,12 +1,10 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
-"""Shared kernel-layer utilities.
+"""The Pallas kernels (``lexbfs_fused``, ``peo_check``) and their shared
+utilities.
 
 :data:`dispatch_counter` counts host-level compiled-program launches —
 each tick is one host->device dispatch (a jit call or a ``pallas_call``
-invocation from Python). The fused-pipeline benchmarks read deltas off it
-to report *measured* dispatches per work unit (``BENCH_kernels.json``).
+invocation from Python). ``tests/test_exact_counts.py`` reads deltas
+off it to hold the *measured* dispatches per work unit.
 
 Since PR 9 the counter is an alias over the obs metrics registry
 (``repro_dispatches_total`` in :data:`repro.obs.registry`) and the

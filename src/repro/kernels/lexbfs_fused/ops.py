@@ -10,8 +10,8 @@ tests/test_lexbfs_fused.py).
 ``interpret=None`` (default) resolves through
 :func:`repro.kernels.resolve_interpret`: interpreted on CPU hosts,
 compiled by Mosaic on a TPU. The module-level :data:`dispatch_counter` ticks once
-per host-level launch — benchmarks read it to report measured
-dispatches-per-unit (``BENCH_kernels.json``).
+per host-level launch; ``tests/test_exact_counts.py`` reads it to hold
+the dispatches-per-unit contract.
 """
 from __future__ import annotations
 

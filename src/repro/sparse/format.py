@@ -136,7 +136,7 @@ class CSRGraph:
         return self.nnz / (n * n) if n else 0.0
 
     def stats(self) -> Dict[str, float]:
-        """Degree / fill statistics for routing, logging, and benchmarks."""
+        """Degree / fill statistics for routing and logging."""
         deg = self.degrees()
         n = self.n_nodes
         return {

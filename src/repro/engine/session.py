@@ -395,17 +395,20 @@ class ChordalityEngine:
             with obs.span("dispatch", backend=backend.name, kind=kind):
                 out = fn(payload)
             exec_ms = (_clock.now() - t1) * 1e3
-        sample = (
-            backend.name, unit.n_pad,
-            float(np.mean([graphs[i].n_edges for i in unit.indices]))
-            / float(unit.n_pad * unit.n_pad) if unit.indices else 0.0,
-            unit.batch, int(getattr(backend, "device_count", 1) or 1),
-            exec_ms * 1e3 / max(unit.batch, 1))
-        self._router_samples.append(sample)
-        self._router_samples_total += 1
-        excess = len(self._router_samples) - self._router_samples_cap
-        if excess > 0:
-            del self._router_samples[:excess]
+        # A sibling of ``unit``: the density pass reads every graph's
+        # adjacency, on the lane, before any future resolves.
+        with obs.span("router_sample", graphs=len(unit.indices)):
+            sample = (
+                backend.name, unit.n_pad,
+                float(np.mean([graphs[i].n_edges for i in unit.indices]))
+                / float(unit.n_pad * unit.n_pad) if unit.indices else 0.0,
+                unit.batch, int(getattr(backend, "device_count", 1) or 1),
+                exec_ms * 1e3 / max(unit.batch, 1))
+            self._router_samples.append(sample)
+            self._router_samples_total += 1
+            excess = len(self._router_samples) - self._router_samples_cap
+            if excess > 0:
+                del self._router_samples[:excess]
         return out[: len(unit.indices)], backend.name, exec_ms, sample
 
     def execute_unit_witness(self, unit, graphs: Sequence[Graph]):

@@ -50,7 +50,7 @@ class Graph:
         if self.indices is not None:
             return len(self.indices)
         if self.adj is not None:
-            return int(self.adj.sum())
+            return int(np.count_nonzero(self.adj))
         return 0
 
     def with_dense(self) -> "Graph":

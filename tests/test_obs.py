@@ -269,6 +269,33 @@ def test_completed_request_trace_is_closed_and_partitions(mode):
     assert root in _request_roots(sink)
 
 
+def test_router_sample_span_follows_unit_under_exec():
+    sink = obs.ListSink()
+    obs.enable_tracing(sink)
+    with AsyncChordalityEngine(
+            config=ServiceConfig(max_batch=4, max_wait_ms=1.0),
+            backend="jax_fast") as svc:
+        resp = svc.submit(G.random_chordal(20, k=3, seed=0)).result(
+            timeout=120)
+    obs.disable_tracing()
+    root = resp.trace
+    _stage_sum_equals_wall(root)
+    exec_span = root.find("exec")
+    names = [c.name for c in exec_span.children]
+    assert names.count("unit") == 1 and names.count("router_sample") == 1
+    unit = exec_span.find("unit")
+    (sample,) = [c for c in exec_span.children if c.name == "router_sample"]
+    assert sample.closed and sample.attrs["graphs"] == 1
+    assert sample.children == []
+    assert unit.t_end <= sample.t_start <= sample.t_end <= exec_span.t_end
+    # unit still holds realize and dispatch, and nothing of the sample
+    assert [c.name for c in unit.children
+            if c.name in ("realize", "dispatch")] == ["realize", "dispatch"]
+    for c in unit.children:
+        assert unit.t_start <= c.t_start <= c.t_end <= unit.t_end
+    assert unit.find("router_sample") is None
+
+
 def test_cancelled_request_trace_closes_with_outcome():
     sink = obs.ListSink()
     obs.enable_tracing(sink)

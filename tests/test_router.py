@@ -325,6 +325,51 @@ def test_stats_surface_unit_samples():
 
 
 # ---------------------------------------------------------------------------
+# The calibration row's density: ``Graph.n_edges`` counts a dense
+# adjacency's set entries, the same number a bool sum gives.
+# ---------------------------------------------------------------------------
+_POOL = G.dense_random(48, p=0.5, seed=3).adj
+
+
+def _n_edges_case(case):
+    """(graph, the count ``int(adj.sum())`` gives) for one storage case."""
+    if case == "contiguous":
+        adj = np.ascontiguousarray(_POOL[:40, :40])
+    elif case == "prefix_view":
+        adj = _POOL[:37, :37]               # strided, as the traffic sends
+    elif case == "int8":
+        adj = _POOL[:33, :33].astype(np.int8)
+    elif case == "empty":
+        adj = np.zeros((16, 16), dtype=bool)
+    else:                                   # "edges": the edge list wins
+        adj = np.zeros((8, 8), dtype=bool)
+        g = Graph(n_nodes=8, adj=adj,
+                  edges=np.array([[0, 1, 2], [1, 0, 3]], dtype=np.int32))
+        return g, 3
+    return Graph(n_nodes=adj.shape[0], adj=adj), int(adj.sum())
+
+
+@pytest.mark.parametrize(
+    "case", ["contiguous", "prefix_view", "int8", "empty", "edges"])
+def test_n_edges_equals_bool_sum(case):
+    g, want = _n_edges_case(case)
+    assert g.n_edges == want
+    assert isinstance(g.n_edges, int)
+
+
+def test_fixed_backend_unit_sample_density_is_mean_bool_sum():
+    graphs = [Graph(n_nodes=n, adj=_POOL[:n, :n])
+              for n in (33, 40, 47, 48)]
+    eng = ChordalityEngine(backend="numpy_ref", max_batch=4)
+    res = eng.run(graphs)
+    (sample,) = res.stats.unit_samples
+    name, n_pad, density, batch, device_count, _ = sample
+    assert (name, n_pad, batch, device_count) == ("numpy_ref", 64, 4, 1)
+    want = float(np.mean([g.adj.sum() for g in graphs])) / (64 * 64)
+    assert density == want
+
+
+# ---------------------------------------------------------------------------
 # device_count feature (PR 10): mesh-aware pricing, clamped to fitted
 # support so single-device logs never extrapolate multi-device costs.
 # ---------------------------------------------------------------------------

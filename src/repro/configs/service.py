@@ -145,9 +145,11 @@ class ServiceConfig:
         of a monotonically growing list.
       n_lanes: executor lanes (PR 10). 1 (the default) is the classic
         single-executor service. With ``n_lanes > 1`` the service runs
-        one executor thread per lane — one per device or mesh slice —
-        with least-loaded dispatch and weighted work-stealing, so a
-        slow lane never stalls the admission loop (DESIGN.md §16).
+        one executor thread per lane with least-loaded dispatch and
+        weighted work-stealing, so a slow lane never stalls the
+        admission loop; lane ``i`` runs its units on
+        ``jax.local_devices()[i % n]`` (all lanes share a ``sharded``
+        backend's mesh) (DESIGN.md §16).
       lane_weights: optional per-lane steal weights, length ``n_lanes``.
         A lane's share of stolen work scales with its weight — weight 2
         steals twice as eagerly as weight 1 (keeps a fast device fed

@@ -46,6 +46,7 @@ warmup and generic callers stay uniform.
 """
 from __future__ import annotations
 
+import copy
 import dataclasses
 from typing import Callable, Dict, Optional, Tuple
 
@@ -83,8 +84,9 @@ def _pack(adjs):
 def _fused_runner(kernel, put, devices: int = 1):
     """Verdict executable over one fused dispatch, split into stage spans
     under the session's ``dispatch``: ``pack`` (:func:`_pack`), ``h2d``
-    (``put`` of the int8 unit on the device, or split over a mesh's
-    ``devices``; attrs ``bytes``, the host bytes put, and ``devices``),
+    (``put`` of the int8 unit on the backend's device, or split over a
+    mesh's ``devices``; attrs ``bytes``, the host bytes put, and
+    ``devices``),
     ``device`` (``kernel`` until its verdicts are ready) and ``d2h``."""
 
     def run(adjs: np.ndarray) -> np.ndarray:
@@ -143,19 +145,25 @@ class ChordalityBackend:
     #: Devices a work unit spans on this backend — the router's
     #: ``device_count`` cost feature. Mesh backends override.
     device_count: int = 1
+    #: The device a one-device backend's executables are pinned to
+    #: (:meth:`on_device`); None runs them on JAX's default device.
+    device = None
 
     def cache_scope(self) -> str:
         """Which platform/device the compiled executables are pinned to —
         the compile cache's scope key component (DESIGN.md §16).
 
         Host backends share one ``"host"`` scope; single-device jit
-        backends are keyed per platform + default device (``"cpu:0"``);
-        mesh backends override with their mesh signature
-        (``"cpu:mesh8"``) so an executable compiled against one device
-        slice is never served to another.
+        backends are keyed per platform + device: their pinned device
+        (``"tpu:2"``), else the default device (``"cpu:0"``); mesh
+        backends override with their mesh signature (``"cpu:mesh8"``) so
+        an executable compiled against one device slice is never served
+        to another.
         """
         if not self.caps.device:
             return "host"
+        if self.device is not None:
+            return f"{self.device.platform}:{self.device.id}"
         scope = self.__dict__.get("_cache_scope")
         if scope is None:
             import jax
@@ -163,6 +171,30 @@ class ChordalityBackend:
             scope = f"{jax.default_backend()}:0"
             self.__dict__["_cache_scope"] = scope
         return scope
+
+    def on_device(self, device) -> "ChordalityBackend":
+        """This backend with its executables pinned to ``device``: a copy
+        that puts each unit there and whose :meth:`cache_scope` names it.
+        Host backends run where they are and return themselves; mesh
+        backends override (their mesh is their placement)."""
+        if not self.caps.device:
+            return self
+        pinned = copy.copy(self)
+        pinned.__dict__.pop("_cache_scope", None)
+        pinned.device = device
+        return pinned
+
+    def _put(self, x):
+        """``x`` on this backend's device: committed there by
+        ``jax.device_put`` when pinned (:meth:`on_device`), else put on
+        JAX's default device by ``jnp.asarray``."""
+        if self.device is None:
+            import jax.numpy as jnp
+
+            return jnp.asarray(x)
+        import jax
+
+        return jax.device_put(x, self.device)
 
     def compile_batch(
         self, n_pad: int, batch: int
@@ -328,10 +360,14 @@ class _JaxBackendBase(ChordalityBackend):
             return peo_check(adj, order_fn(adj))
 
         fn = jax.jit(jax.vmap(one))
+        device = self.device
 
         def run(adjs: np.ndarray) -> np.ndarray:
             # numpy in, numpy out: jit's implicit device_put beats an
             # explicit jnp.asarray round-trip on the small-unit hot path.
+            # A pinned backend commits the unit to its device first.
+            if device is not None:
+                adjs = jax.device_put(adjs, device)
             return np.asarray(fn(adjs))
 
         return run
@@ -486,13 +522,11 @@ class PallasPeoBackend(ChordalityBackend):
 
     def _fused_verdicts(self, kernel):
         """Verdict executable over one call of a fused kernel
-        (``lexbfs_peo_fused`` or its packed variant) on the default
+        (``lexbfs_peo_fused`` or its packed variant) on this backend's
         device."""
-        import jax.numpy as jnp
-
         interpret = self._interpret
         return _fused_runner(lambda x: kernel(x, interpret=interpret)[0],
-                             lambda a8: jnp.asarray(a8))
+                             self._put)
 
     def compile_fused_batch(self, n_pad, batch):
         from repro.kernels.lexbfs_fused.ops import lexbfs_peo_fused
@@ -500,8 +534,6 @@ class PallasPeoBackend(ChordalityBackend):
         return self._fused_verdicts(lexbfs_peo_fused)
 
     def compile_batch(self, n_pad, batch):
-        import jax.numpy as jnp
-
         from repro.core.lexbfs import lexbfs
         from repro.kernels import dispatch_counter
         from repro.kernels.peo_check.ops import peo_check_pallas
@@ -512,7 +544,7 @@ class PallasPeoBackend(ChordalityBackend):
             out = np.zeros(adjs.shape[0], dtype=bool)
             for i, adj in enumerate(adjs):
                 with obs.span("h2d"):
-                    a = _stage_end(jnp.asarray(adj))
+                    a = _stage_end(self._put(adj))
                 dispatch_counter.tick(2)   # LexBFS jit + PEO kernel launch
                 with obs.span("device"):
                     ok = _stage_end(
@@ -540,8 +572,6 @@ class PallasPeoBackend(ChordalityBackend):
         return self._fused_verdicts(lexbfs_peo_fused_packed)
 
     def compile_fused_witness_batch(self, n_pad, batch):
-        import jax.numpy as jnp
-
         from repro.kernels.lexbfs_fused.ops import lexbfs_peo_fused_witness
         from repro.witness import witness_batch_from_fused_raw
 
@@ -550,7 +580,7 @@ class PallasPeoBackend(ChordalityBackend):
         def run(adjs, n_nodes):
             adjs, a8 = _pack(adjs)
             with obs.span("h2d", bytes=a8.nbytes, devices=1):
-                x = _stage_end(jnp.asarray(a8))
+                x = _stage_end(self._put(a8))
             with obs.span("device"):
                 outs = _stage_end(
                     lexbfs_peo_fused_witness(x, interpret=interpret))
@@ -642,6 +672,10 @@ class ShardedBackend(ChordalityBackend):
         from repro.engine.mesh import mesh_signature
 
         return mesh_signature(self._get_mesh())
+
+    def on_device(self, device) -> "ShardedBackend":
+        """The mesh is this backend's placement: every lane shares it."""
+        return self
 
     def verdict_kind(self, n_pad: int) -> str:
         from repro.configs.shapes import FUSED_MAX_NPAD
@@ -739,7 +773,7 @@ class CSRBackend(ChordalityBackend):
                 return viol == 0
             from repro.sparse import csr_verdicts_batched
 
-            rp, ci = packed.device_arrays()
+            rp, ci = self._put(packed.row_ptr), self._put(packed.col_idx)
             return np.asarray(csr_verdicts_batched(rp, ci, packed.deg_pad))
 
         return run

@@ -332,11 +332,13 @@ class CompileCache:
     at its first call (``repro_xla_compiles_total``,
     :mod:`repro.obs.compiles`). A hit reuses the executable. The
     hit/miss counters feed the engine's stats — in steady-state serving,
-    misses stay flat.
+    misses stay flat. Lookups hold a lock, so executor lanes and the
+    per-device warm-up threads build each key once and lose no count.
     """
 
     def __init__(self):
         self._fns: Dict[Tuple[str, str, str, int, int], Callable] = {}
+        self._lock = threading.Lock()
         self.hits = 0
         self.misses = 0
 
@@ -351,8 +353,12 @@ class CompileCache:
             kind: str = "verdict") -> Callable:
         scope = backend.cache_scope()
         key = (backend.name, scope, kind, n_pad, batch)
-        fn = self._fns.get(key)
-        if fn is None:
+        with self._lock:
+            fn = self._fns.get(key)
+            if fn is not None:
+                self.hits += 1
+                _M_CACHE_HITS.inc()
+                return fn
             self.misses += 1
             _M_CACHE_MISSES.inc()
             if kind == "verdict":
@@ -371,7 +377,4 @@ class CompileCache:
             else:
                 raise ValueError(f"unknown executable kind {kind!r}")
             self._fns[key] = fn
-        else:
-            self.hits += 1
-            _M_CACHE_HITS.inc()
         return fn

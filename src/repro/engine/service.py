@@ -19,11 +19,15 @@ that gap with the classic serving triad:
   to the least-loaded lane (weighted by ``lane_weights``) and an idle
   lane steals from the most-loaded lane's tail, so a slow lane — a slow
   device, in the mesh deployment of DESIGN.md §16 — never stalls the
-  admission loop or starves the other lanes. Every lane drives the
-  session's single execution path (``ChordalityEngine.execute_unit``):
-  same compile cache, same realize contract (dense or padded-CSR), so
-  admission overlaps execution and the compiled-shape universe is
-  identical to offline runs.
+  admission loop or starves the other lanes. Lane ``i`` is bound to
+  ``jax.local_devices()[i % n]`` (with one lane or one device, to JAX's
+  default device): it runs every verdict unit it takes, stolen ones
+  too, on a one-device backend pinned there, and all lanes share a
+  ``sharded`` backend's one mesh. Every lane drives the session's
+  single execution path (``ChordalityEngine.execute_unit``): same
+  compile cache (scoped per device), same realize contract (dense or
+  padded-CSR), so admission overlaps execution and the compiled-shape
+  universe is identical to offline runs.
 
 Each ``submit`` returns a ``concurrent.futures.Future`` resolving to a
 :class:`ServiceResponse` (verdict, optional certificate, optional checkable
@@ -72,7 +76,7 @@ from __future__ import annotations
 
 import dataclasses
 import threading
-from concurrent.futures import Future
+from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Deque, Dict, List, Optional, Sequence, Tuple, Union
 
 import collections
@@ -94,6 +98,21 @@ class QueueFullError(RuntimeError):
 
 class ServiceClosedError(RuntimeError):
     """``submit`` after ``shutdown`` began."""
+
+
+def _lane_devices(n_lanes: int) -> list:
+    """The device each executor lane runs its verdict units on: lane
+    ``i`` takes ``jax.local_devices()[i % n]``. With one lane or one
+    local device every lane gets None, JAX's default device, where the
+    single-executor service always ran."""
+    if n_lanes == 1:
+        return [None]
+    import jax
+
+    devices = jax.local_devices()
+    if len(devices) == 1:
+        return [None] * n_lanes
+    return [devices[i % len(devices)] for i in range(n_lanes)]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -449,10 +468,16 @@ class AsyncChordalityEngine:
         self._m_refits = _m.counter(
             "repro_router_refits_total",
             "online router refits that updated at least one backend")
+        self._m_steals = _m.counter(
+            "repro_lane_steals_total",
+            "units an idle executor lane took from another lane's queue",
+            labels=("lane",))
         # Executor lanes (PR 10, DESIGN.md §16): one deque + one daemon
-        # thread per lane, all under one lane lock/condition. n_lanes=1
-        # degenerates to the classic single-executor service.
+        # thread per lane, all under one lane lock/condition, each lane
+        # bound to a device. n_lanes=1 degenerates to the classic
+        # single-executor service.
         n_lanes = self.config.n_lanes
+        self._lane_devices = _lane_devices(n_lanes)
         self._lane_weights: Tuple[float, ...] = (
             self.config.lane_weights
             if self.config.lane_weights is not None
@@ -494,18 +519,36 @@ class AsyncChordalityEngine:
         queue delay. Only call while the service is idle — it drives the
         inner engine's compile cache from the caller's thread.
         ``witness=True`` additionally warms the fused witness executables
-        (for traffic that will ask ``want_witness``).
+        (for traffic that will ask ``want_witness``). Lanes bound to
+        devices warm their verdict executables on each device, one
+        thread per device, so set-up grows by the slowest device rather
+        than by their sum.
         """
         by_bucket = bucket_graphs(sample, self.engine.buckets)
+        plans = []
         for _, idxs in sorted(by_bucket.items()):
             b = 1
             while True:
                 chunk = [sample[i] for i in idxs[:b]]
-                self.engine.warmup_plan(
-                    self.engine.plan(chunk), chunk, witness=witness)
+                plans.append((self.engine.plan(chunk), chunk))
                 if b >= min(len(idxs), self.config.max_batch):
                     break
                 b *= 2
+        devices = list(dict.fromkeys(self._lane_devices))
+
+        def warm(device, witness: bool) -> None:
+            for plan, chunk in plans:
+                self.engine.warmup_plan(
+                    plan, chunk, witness=witness, device=device)
+
+        if len(devices) == 1:
+            warm(devices[0], witness)
+        else:
+            # Witness executables run on the default device whatever the
+            # lane (see _execute): the first device's pass warms them.
+            with ThreadPoolExecutor(len(devices)) as pool:
+                list(pool.map(warm, devices,
+                              [witness] + [False] * (len(devices) - 1)))
         return self
 
     def submit(
@@ -1049,6 +1092,7 @@ class AsyncChordalityEngine:
         victim = max(victims, key=lambda j: len(self._lane_queues[j]))
         vq = self._lane_queues[victim]
         k = min(len(vq), max(1, int(round(self._lane_weights[lane]))))
+        self._m_steals.inc(k, lane=lane)
         stolen = [vq.pop() for _ in range(k)]   # tail: newest first
         stolen.reverse()                        # run oldest stolen first
         dq.extend(stolen[1:])
@@ -1093,6 +1137,7 @@ class AsyncChordalityEngine:
 
     def _execute(self, au: _AdmittedUnit, lane: int = 0) -> None:
         t_start = _clock.now()
+        device = self._lane_devices[lane]
         live = [r.future.set_running_or_notify_cancel()
                 for r in au.requests]
         graphs = [r.graph for r in au.requests]
@@ -1100,9 +1145,10 @@ class AsyncChordalityEngine:
         # so the session's unit/realize/compile/dispatch spans nest inside
         # it, emit=False because it is adopted into each live request's
         # root rather than emitted standalone. The ``lane`` attribute ties
-        # the span to the executor lane that ran the unit. Queue spans
-        # close at its exact start instant so queue+exec+finalize sums to
-        # the root duration.
+        # the span to the executor lane that ran the unit, ``device`` (set
+        # once it ran) to the cache scope it ran in. Queue spans close at
+        # its exact start instant so queue+exec+finalize sums to the root
+        # duration.
         exec_span = self._tracer.span(
             "exec", emit=False, n_pad=au.unit.n_pad, batch=au.unit.batch,
             lane=lane)
@@ -1145,7 +1191,7 @@ class AsyncChordalityEngine:
                         else exec_ms + wit_ms
                 elif unit_recs is None:
                     out, backend_name, exec_ms = self.engine.execute_unit(
-                        au.unit, graphs)
+                        au.unit, graphs, device=device)
         except Exception as e:
             with self._lock:
                 for r, ok in zip(au.requests, live):
@@ -1159,8 +1205,16 @@ class AsyncChordalityEngine:
                     self._backlog -= 1
                 self._done_cv.notify_all()
             return
+        # Verdict units ran on the lane's device; witness and recognition
+        # units on the engine's default-device instances.
+        ran_on = device if unit_wits is None and unit_recs is None else None
+        try:
+            scope = self.engine._resolve(backend_name, ran_on).cache_scope()
+        except Exception:
+            scope = "host"
         if self._tracer.enabled:
             exec_span.attrs["backend"] = backend_name
+            exec_span.attrs["device"] = scope
         # Certificates are per-request extras: one failing must neither
         # fail its unit-mates nor kill the executor thread.
         certs: List[Optional[Certificate]] = []
@@ -1184,12 +1238,8 @@ class AsyncChordalityEngine:
             if unit_wits is not None:
                 self.stats.witness_upgraded += 1
                 kinds.append("witness")
-            try:
-                device = self.engine._resolve(backend_name).cache_scope()
-            except Exception:
-                device = "host"
             self._m_units.inc(
-                kind="+".join(kinds) or "verdict", device=device)
+                kind="+".join(kinds) or "verdict", device=scope)
             self.stats.record_exec_latency(exec_ms)
             self._m_exec_ms.observe(exec_ms)
             occ = sum(live)       # cancelled-after-drain slots don't count

@@ -23,7 +23,9 @@
 The engine owns one backend instance (or, under ``backend="auto"``, a
 router plus lazily-built instances of its candidates) and one compile cache
 for its lifetime, so repeated ``run`` calls amortize compilation the way a
-serving process does. All shape planning goes through
+serving process does. Service lanes bound to devices run their units on
+copies pinned to each device (``ChordalityBackend.on_device``), keyed in
+the same cache by device scope. All shape planning goes through
 ``repro.engine.planner`` — callers never pad or batch by hand. Work units
 whose backend carries the ``sparse`` capability are realized as padded CSR
 batches (no dense matrix on that path); everything else gets the dense
@@ -33,6 +35,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import threading
 from typing import Dict, List, Optional, Sequence, Union
 
 import numpy as np
@@ -153,6 +156,12 @@ class ChordalityEngine:
     ):
         self.router = None
         self._instances: Dict[str, ChordalityBackend] = {}
+        # (backend name, device) -> that backend pinned to the device
+        # (ChordalityBackend.on_device): the instances executor lanes
+        # bound to devices run their units on, one per key whatever the
+        # number of lanes resolving it at once.
+        self._pinned: Dict[tuple, ChordalityBackend] = {}
+        self._pinned_lock = threading.Lock()
         if isinstance(backend, str) and backend == "auto":
             if backend_opts:
                 raise ValueError(
@@ -190,20 +199,31 @@ class ChordalityEngine:
         self._router_samples_total = 0
 
     # -- backend resolution ------------------------------------------------
-    def _resolve(self, name: Optional[str]) -> ChordalityBackend:
-        """Unit backend name -> instance (engine-owned, built lazily)."""
+    def _resolve(self, name: Optional[str],
+                 device=None) -> ChordalityBackend:
+        """Unit backend name -> instance (engine-owned, built lazily);
+        with ``device``, that instance pinned to the device (host and
+        mesh backends stay as they are, see ``on_device``)."""
         if name is None:
             if self.backend is None:
                 raise RuntimeError(
                     "auto engine got an unannotated work unit; plans must "
                     "come from ChordalityEngine.plan()")
-            return self.backend
-        if self.backend is not None and self.backend.name == name:
-            return self.backend
-        inst = self._instances.get(name)
-        if inst is None:
-            inst = self._instances[name] = make_backend(name)
-        return inst
+            inst = self.backend
+        elif self.backend is not None and self.backend.name == name:
+            inst = self.backend
+        else:
+            inst = self._instances.get(name)
+            if inst is None:
+                inst = self._instances[name] = make_backend(name)
+        if device is None:
+            return inst
+        with self._pinned_lock:
+            pinned = self._pinned.get((inst.name, device))
+            if pinned is None:
+                pinned = self._pinned[(inst.name, device)] = \
+                    inst.on_device(device)
+        return pinned
 
     def _resolve_witness(self, name: Optional[str]) -> ChordalityBackend:
         """Like :meth:`_resolve` but guarantees the witness capability.
@@ -314,7 +334,7 @@ class ChordalityEngine:
         return self
 
     def warmup_plan(self, plan: Plan, graphs: Optional[Sequence[Graph]] = None,
-                    witness: Optional[bool] = None):
+                    witness: Optional[bool] = None, device=None):
         """Pre-compile exactly the shapes a plan needs.
 
         For dense backends the (backend, n_pad, batch) key fully determines
@@ -323,12 +343,13 @@ class ChordalityEngine:
         of their *contents* — pass the plan's ``graphs`` to warm those
         exact buckets; without graphs, sparse units warm the minimum
         buckets only (best effort — real traffic may still compile once
-        per new edge-count bucket).
+        per new edge-count bucket). ``device`` warms the verdict
+        executables :meth:`execute_unit` runs there.
         """
         witness = self.witness_default if witness is None else witness
         seen = set()
         for unit in plan.units:
-            backend = self._resolve(unit.backend)
+            backend = self._resolve(unit.backend, device)
             key = (backend.name, unit.n_pad, unit.batch)
             fn = self.cache.get(
                 backend, unit.n_pad, unit.batch,
@@ -356,7 +377,7 @@ class ChordalityEngine:
         return self
 
     # -- execution ---------------------------------------------------------
-    def execute_unit(self, unit, graphs: Sequence[Graph]):
+    def execute_unit(self, unit, graphs: Sequence[Graph], device=None):
         """Run one work unit: ``(verdicts, backend_name, exec_ms)``.
 
         The single execution path shared by :meth:`run` and the async
@@ -365,18 +386,20 @@ class ChordalityEngine:
         from the compile cache, run it. ``verdicts`` align to
         ``unit.indices`` order (padding slots already dropped); ``exec_ms``
         covers the executable call only (realize/compile time is visible
-        through the cache counters instead).
+        through the cache counters instead). ``device`` runs the unit on
+        the backend pinned there (an executor lane's device).
         """
         out, backend_name, exec_ms, _ = self._execute_unit_sampled(
-            unit, graphs)
+            unit, graphs, device)
         return out, backend_name, exec_ms
 
-    def _execute_unit_sampled(self, unit, graphs: Sequence[Graph]):
+    def _execute_unit_sampled(self, unit, graphs: Sequence[Graph],
+                              device=None):
         """:meth:`execute_unit` plus the unit's router-calibration sample
         (logged engine-wide and returned, so ``run`` can attribute its own
         units' samples to its stats without racing the async executor's
         appends to the shared log)."""
-        backend = self._resolve(unit.backend)
+        backend = self._resolve(unit.backend, device)
         kind = backend.verdict_kind(unit.n_pad)
         # A dense unit lives in a pooled buffer, returned when the stack
         # closes: after the executable has handed back host verdicts, so

@@ -702,7 +702,7 @@ def test_multilane_failed_unit_closes_traces():
     cfg = ServiceConfig(max_batch=1, max_wait_ms=0.0, n_lanes=2)
     svc = AsyncChordalityEngine(config=cfg, backend="numpy_ref")
     try:
-        def boom(unit, graphs):
+        def boom(unit, graphs, device=None):
             raise RuntimeError("lane boom")
 
         svc.engine.execute_unit = boom
@@ -716,3 +716,64 @@ def test_multilane_failed_unit_closes_traces():
     roots = _request_roots(sink)
     assert len(roots) == 3 and all(r.closed for r in roots)
     assert {r.attrs["outcome"] for r in roots} == {"failed"}
+
+
+def test_exec_span_carries_the_device_it_ran_on():
+    """``exec`` carries ``device``, the cache scope of the backend that
+    ran the unit, next to ``lane``: ``host`` for numpy_ref, the default
+    device's ``cpu:0`` for a jit backend on the one CPU device."""
+    sink = obs.ListSink()
+    obs.enable_tracing(sink)
+    cfg = ServiceConfig(max_batch=2, max_wait_ms=0.5, n_lanes=2)
+    for backend, scope in (("numpy_ref", "host"), ("jax_fast", "cpu:0")):
+        with AsyncChordalityEngine(config=cfg, backend=backend) as svc:
+            gather(svc.submit_many([G.cycle(9), G.clique(5)]), timeout=120)
+        roots = _request_roots(sink)
+        assert len(roots) == 2
+        for r in roots:
+            ex = r.find("exec")
+            assert ex.attrs["device"] == scope and ex.attrs["lane"] in (0, 1)
+        sink.spans.clear()
+    obs.disable_tracing()
+
+
+def test_lane_steals_counter_counts_a_forced_steal():
+    """Every unit lands on lane 0's queue and the first unit to run is
+    held: whichever lane holds it, lane 1 takes exactly one of the two
+    units from lane 0's queue, so ``repro_lane_steals_total{lane="1"}``
+    moves by one and lane 0 steals nothing."""
+    release, started = threading.Event(), threading.Event()
+    first = threading.Lock()
+    cfg = ServiceConfig(max_batch=1, max_wait_ms=0.0, n_lanes=2)
+    svc = AsyncChordalityEngine(config=cfg, backend="numpy_ref")
+    before = [svc._m_steals.value(lane=k) for k in (0, 1)]
+    orig = svc._execute
+
+    def to_lane_0(au):
+        with svc._lane_cv:
+            svc._lane_queues[0].append(au)
+            svc._lane_cv.notify_all()
+
+    def gated(au, lane=0):
+        if first.acquire(blocking=False):
+            started.set()
+            release.wait(timeout=60)
+        return orig(au, lane)
+
+    svc._dispatch_unit, svc._execute = to_lane_0, gated
+    try:
+        held = svc.submit(G.cycle(9))
+        assert started.wait(timeout=30)
+        assert svc.submit(G.clique(4)).result(timeout=60).verdict
+        assert not held.done()
+        release.set()
+        assert held.result(timeout=60).verdict is False
+        tel = svc.telemetry()
+    finally:
+        release.set()
+        svc.shutdown()
+    after = [svc._m_steals.value(lane=k) for k in (0, 1)]
+    assert [a - b for a, b in zip(after, before)] == [0, 1]
+    assert "repro_lane_steals_total" in tel["metrics"]
+    assert re.search(r'^repro_lane_steals_total\{lane="1"\} [0-9.]+$',
+                     obs.render_prometheus(), re.M)

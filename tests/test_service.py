@@ -636,9 +636,9 @@ def test_expired_requests_release_slots_at_drain():
 
         orig_exec = svc.engine.execute_unit
 
-        def spy_exec(unit, graphs):
+        def spy_exec(unit, graphs, device=None):
             executed_pads.append(unit.n_pad)
-            return orig_exec(unit, graphs)
+            return orig_exec(unit, graphs, device)
 
         svc.engine.route_unit = slow_route
         svc.engine.execute_unit = spy_exec
@@ -731,8 +731,8 @@ def test_shutdown_no_drain_is_terminal():
     svc = AsyncChordalityEngine(config=cfg, backend="numpy_ref")
     calls = []
     orig_exec = svc.engine.execute_unit
-    svc.engine.execute_unit = lambda unit, graphs: (
-        calls.append(unit.n_pad), orig_exec(unit, graphs))[1]
+    svc.engine.execute_unit = lambda unit, graphs, device=None: (
+        calls.append(unit.n_pad), orig_exec(unit, graphs, device))[1]
     futs = svc.submit_many([G.cycle(9), G.clique(9)])
     svc.shutdown(drain=False)
     # no executor work after shutdown returned, and no way to add any
